@@ -129,7 +129,8 @@ def make_inner_step(loss_fn: Callable, tcfg: TrainConfig,
 
 def inner_phase(inner_step, replica_params, inner_state, batches,
                 step0, *, active_mask=None):
-    """H inner steps for all k replicas (vmap over k, scan over H).
+    """H inner steps for all k replicas (scan over H, one replica after
+    another).
 
     batches: tokens (k, H, B, S) or a dict of such; step0: scalar global
     inner-step index of the phase start (for the shared lr schedule).
@@ -137,6 +138,14 @@ def inner_phase(inner_step, replica_params, inner_state, batches,
     (adaptive compute pool; they burn no "real" compute on hardware
     because their island simply isn't there).
     Returns (replica_params, inner_state, metrics (k, H) dict).
+
+    Each replica is read from the stacked (k, ...) carry, stepped, and
+    written back in place, so the device holds one replica's working
+    state and activations at a time beside the stacked state. A vmap
+    over k would batch the replicas instead, but the layer scan inside
+    the model then moves every stacked leaf to a layer-major layout: a
+    second copy of all replicas' params and AdamW moments, which at the
+    150M model with k=2 does not fit one 16 GB chip.
     """
     def one_replica(params, opt_state, batches_h, active):
         def body(carry, xs):
@@ -157,8 +166,20 @@ def inner_phase(inner_step, replica_params, inner_state, batches,
     k = jax.tree.leaves(replica_params)[0].shape[0]
     if active_mask is None:
         active_mask = jnp.ones((k,), jnp.float32)
-    return jax.vmap(one_replica)(replica_params, inner_state, batches,
-                                 active_mask)
+    take = lambda tree, i: jax.tree.map(lambda x: x[i], tree)
+    ms_shape = jax.eval_shape(one_replica, take(replica_params, 0),
+                              take(inner_state, 0), take(batches, 0),
+                              active_mask[0])[2]
+    ms0 = jax.tree.map(lambda s: jnp.zeros((k,) + s.shape, s.dtype),
+                       ms_shape)
+
+    def body(i, carry):
+        out = one_replica(*take(carry[:2], i), take(batches, i),
+                          active_mask[i])
+        return jax.tree.map(lambda x, y: x.at[i].set(y), carry, out)
+
+    return jax.lax.fori_loop(0, k, body,
+                             (replica_params, inner_state, ms0))
 
 
 # ---------------------------------------------------------------------------

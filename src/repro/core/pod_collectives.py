@@ -57,7 +57,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 POD_AXIS = "pod"
@@ -182,6 +181,16 @@ def stream_state_specs(state, axis: str = POD_AXIS):
                   else rep(state.inflight)))
 
 
+def init_on_mesh(init_fn, params, mesh, axis: str = POD_AXIS):
+    """Run ``init_fn(params) -> StreamState`` with its outputs placed on
+    ``mesh`` per ``stream_state_specs``: each pod builds only its own
+    replica band, so the k stacked replicas never sit on one device."""
+    shapes = jax.eval_shape(init_fn, params)
+    shardings = jax.tree.map(lambda _, s: NamedSharding(mesh, s), shapes,
+                             stream_state_specs(shapes, axis))
+    return jax.jit(init_fn, out_shardings=shardings)(params)
+
+
 def shard_stream_state(state, mesh, axis: str = POD_AXIS):
     """Place a StreamState on ``mesh``: replica state banded over the
     pod axis, shared state replicated. Use before the first sharded
@@ -207,10 +216,10 @@ def shard_round_body(core, mesh, state_specs):
     """Wrap an un-jitted streaming round core in shard_map over the pod
     axis: state per ``state_specs``; key, masks and weights replicated;
     outputs (state, metrics) with metrics replicated (they are pmean'd
-    inside). check_rep=False: replication of the shared state is
+    inside). check_vma=False: replication of the shared state is
     guaranteed by construction (all pods consume identical collective
     results), which the static checker cannot see."""
-    return shard_map(core, mesh=mesh,
-                     in_specs=(state_specs, P(), P(), P(), P()),
-                     out_specs=(state_specs, P()),
-                     check_rep=False)
+    return jax.shard_map(core, mesh=mesh,
+                         in_specs=(state_specs, P(), P(), P(), P()),
+                         out_specs=(state_specs, P()),
+                         check_vma=False)

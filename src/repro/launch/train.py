@@ -38,6 +38,23 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it
+    itself), else the fixed ``<repo>/.jax_cache`` — a fixed path, since
+    the path is part of the cache key. Call from entry points only."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def _int_list(spec: str, k: int, name: str) -> tuple:
     """Parse a comma list of ints; a single value broadcasts to k."""
     try:
@@ -414,7 +431,6 @@ def run(args, recorder=None):
                  f"{max(frag_wire)} B/exchange")
     elif dcfg.streaming_fragments:
         from repro.core import streaming
-        state = streaming.init_state(params, dcfg)
         plan = streaming.sync_plan(params, dcfg)
         round_wire = sum(row["wire_bytes"] for row in plan)
         rec.attach_wire_plan(plan)
@@ -438,10 +454,16 @@ def run(args, recorder=None):
                     "XLA_FLAGS=--xla_force_host_platform_device_"
                     "count=N (a multiple of k) before jax starts")
             mesh = make_pod_mesh(pods)
+            # each pod builds its own band: k stacked replicas would not
+            # fit one device at published widths
+            state = pod_collectives.init_on_mesh(
+                lambda p: streaming.init_state(p, dcfg), params, mesh)
             rec.note(f"sharded transport: "
                      f"{pod_collectives.pods_of(mesh)} "
                      f"pods × {args.k // pod_collectives.pods_of(mesh)} "
                      "replicas/pod")
+        else:
+            state = streaming.init_state(params, dcfg)
     else:
         state = diloco.init_state(params, dcfg)
         round_wire = diloco.outer_wire_bytes(params, dcfg)
@@ -449,21 +471,22 @@ def run(args, recorder=None):
                                "apply_step": args.H,
                                "wire_bytes": float(round_wire),
                                "wire_dtype": dcfg.outer_grad_dtype}])
+    del params                # the state holds its own copy
     # ---- resume + (re-)placement ----
-    # Snapshots live at HOST placement: the example captured here (its
-    # arrays outlive donation — only shapes/dtypes are read) restores
-    # a snapshot saved under ANY pod count; shard_stream_state then
-    # re-places it onto THIS run's mesh — the elastic-resize path.
-    snapshot_example = resilience.wrap(state, key, 0)
+    # Snapshots live at HOST placement: the example captured here (only
+    # shapes/dtypes, so it holds no device memory) restores a snapshot
+    # saved under ANY pod count; shard_stream_state then re-places it
+    # onto THIS run's mesh — the elastic-resize path.
+    snapshot_example = jax.eval_shape(lambda: resilience.wrap(state, key,
+                                                              0))
     rounds_done = 0
     if resume_step is not None:
         state, key, rounds_done = resilience.unwrap(
             mgr.load(resume_step, snapshot_example))
         rec.note(f"resumed snapshot {resume_step}: "
                  f"{rounds_done} round(s) done")
-    if mesh is not None:
-        from repro.core import pod_collectives
-        state = pod_collectives.shard_stream_state(state, mesh)
+        if mesh is not None:
+            state = pod_collectives.shard_stream_state(state, mesh)
 
     def load_snapshot(step):
         """Restore snapshot ``step`` and re-place it for this run
@@ -657,9 +680,11 @@ def run(args, recorder=None):
                                    "k": args.k, "H": args.H,
                                    "rounds_done": t})
 
-    rec.note(f"done in {time.time() - t0:.1f}s; "
-             f"entropy floor = {sampler.entropy_floor():.4f} "
-             f"(ppl {np.exp(sampler.entropy_floor()):.2f})")
+    elapsed = time.time() - t0
+    floor = sampler.entropy_floor()
+    rec.note(f"done in {elapsed:.1f}s; "
+             f"entropy floor = {floor:.4f} (ppl {np.exp(floor):.2f})",
+             entropy_floor=floor)
     if args.trace:
         overlap = None
         if mesh is not None and plan \
@@ -936,4 +961,5 @@ def make_parser():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     run(make_parser().parse_args())

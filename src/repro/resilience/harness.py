@@ -28,12 +28,11 @@ def train_cmd(args) -> list:
 
 
 def train_env(*, devices: int | None = None) -> dict:
-    """Environment for a train subprocess: src on PYTHONPATH, CPU
-    platform, optionally a forced host device count (the sharded
-    transport's pods)."""
+    """Environment for a train subprocess: src on PYTHONPATH, the
+    parent's platform, optionally a forced host device count (the
+    sharded transport's pods)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
     if devices is not None:
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "")

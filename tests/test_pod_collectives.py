@@ -287,15 +287,14 @@ def test_int4_scale_blocks_are_pod_local():
     tiny = np.full((1, 256), 1e-3, np.float32)
     d = jnp.asarray(np.concatenate([big, tiny]))            # (k=2, 256)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P, NamedSharding
 
     def body(d_local):
         q = jax.vmap(lambda x: kops.quant_roundtrip(x, "int4"))(d_local)
         return jax.lax.all_gather(q, "pod", axis=0, tiled=True)
 
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("pod"),),
-                           out_specs=P(), check_rep=False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("pod"),),
+                               out_specs=P(), check_vma=False))
     out = np.asarray(fn(jax.device_put(
         d, NamedSharding(mesh, P("pod")))))
     # per-replica blocks: every element within amax/14 of its own value
@@ -439,7 +438,6 @@ def _packed_mean_tree(params, d, m, P, pods, dt):
     every region of the local band, concatenate, ONE gather_wire,
     decode + masked mean — the exact op sequence of
     streaming.packed_send, at the wire level."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Pspec
 
     part = fragments.partition_params(params, P)
@@ -468,11 +466,11 @@ def _packed_mean_tree(params, d, m, P, pods, dt):
                 pend[r.leaf] = fragments.region_put(pend[r.leaf], r, a)
         return jax.tree_util.tree_unflatten(treedef, pend)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: Pspec("pod"), d),),
         out_specs=jax.tree.map(lambda _: Pspec(), params),
-        check_rep=False))
+        check_vma=False))
     return fn(d)
 
 
