@@ -140,15 +140,6 @@ class MarkovMixture:
         _, rest = jax.lax.scan(step, first, keys)
         return jnp.concatenate([first[None], rest], 0).T.astype(jnp.int32)
 
-    # ---- transition probabilities ----
-    def transition_probs(self, tokens, worker: int | None = None):
-        """(n, V) next-token probabilities of stream ``worker``'s chain
-        (the validation mixture when None) at current tokens (n,)."""
-        members, logw = (self._validation() if worker is None
-                         else self._mixture(self.groups[worker:worker + 1]))
-        return _mixture_rows(self, members[0], logw[0],
-                             jnp.asarray(tokens, jnp.int32))
-
     # ---- resharding ----
     def regroup(self, k_workers: int) -> "MarkovMixture":
         """Redistribute this mixture's shards among ``k_workers``

@@ -488,13 +488,22 @@ def run(args, recorder=None):
         if mesh is not None:
             state = pod_collectives.shard_stream_state(state, mesh)
 
+    def place_key(kk):
+        """The sharded run hands its carry key back replicated on the
+        mesh; a key placed anywhere else is another input type, and the
+        run's next call would compile it again."""
+        if mesh is None:
+            return kk
+        return jax.device_put(kk, jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec()))
+
     def load_snapshot(step):
         """Restore snapshot ``step`` and re-place it for this run
         (the guard's rollback path)."""
         st, kk, rd = resilience.unwrap(mgr.load(step, snapshot_example))
         if mesh is not None:
             st = pod_collectives.shard_stream_state(st, mesh)
-        return st, kk, rd
+        return st, place_key(kk), rd
 
     rng = np.random.default_rng(args.seed)
     drops = schedules.drop_masks(rng, args.drop_prob, args.k, args.rounds)
@@ -616,6 +625,7 @@ def run(args, recorder=None):
             return runs[kk]
 
         t = rounds_done
+        key = place_key(key)
         while t < args.rounds:
             n = min(rpc, args.rounds - t)
             if ckpt_every:

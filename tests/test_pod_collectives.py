@@ -648,3 +648,25 @@ def test_hlo_overlap_tau0_stays_eager(setup):
 # Hypothesis property tests for Partition × schedule × pod banding live
 # in tests/test_pod_properties.py — a module-level importorskip there
 # must not take this whole multi-device suite down with it.
+
+
+def test_sharded_cli_run_compiles_each_chunk_once(caplog):
+    """A chunked sharded run through the CLI driver compiles its round
+    program once: the carry key handed back between chunks keeps the
+    placement of the key the first chunk was called with."""
+    import logging
+    from repro.launch import train
+    args = train.make_parser().parse_args(
+        ["--arch", "diloco_150m", "--smoke", "--k", "2", "--H", "2",
+         "--rounds", "3", "--rounds-per-call", "1", "--seq", "16",
+         "--batch", "2", "--eval-batch", "2", "--transport", "sharded",
+         "--stream-fragments", "2", "--pods", "2"])
+    jax.config.update("jax_log_compiles", True)
+    try:
+        with caplog.at_level(logging.WARNING, logger="jax"):
+            rounds = [r for r in train.run(args) if r["kind"] == "round"]
+    finally:
+        jax.config.update("jax_log_compiles", False)
+    compiles = [r for r in caplog.records
+                if r.getMessage().startswith("Compiling jit(run_fn)")]
+    assert len(rounds) == 3 and len(compiles) == 1

@@ -232,6 +232,16 @@ def test_checkpoint_shape_mismatch_raises():
             ckpt.restore(path, {"w": jnp.ones((3, 3))})
 
 
+def _transition_probs(s, toks, worker=None):
+    """(n, V) next-token probabilities of stream ``worker``'s chain (the
+    validation mixture when None) at current tokens ``toks``."""
+    from repro.data import pipeline
+    members, logw = (s._validation() if worker is None
+                     else s._mixture(s.groups[worker:worker + 1]))
+    return pipeline._mixture_rows(s, members[0], logw[0],
+                                  jnp.asarray(toks, jnp.int32))
+
+
 def test_markov_regroup_holds_process_fixed():
     """regroup(k) keeps the validation mixture identical and gives the
     k=1 worker exactly the mixture distribution."""
@@ -239,10 +249,10 @@ def test_markov_regroup_holds_process_fixed():
     s4 = s16.regroup(4)
     s1 = s16.regroup(1)
     toks = jnp.arange(32)
-    np.testing.assert_array_equal(np.asarray(s16.transition_probs(toks)),
-                                  np.asarray(s4.transition_probs(toks)))
-    np.testing.assert_allclose(np.asarray(s1.transition_probs(toks, 0)),
-                               np.asarray(s16.transition_probs(toks)),
+    np.testing.assert_array_equal(np.asarray(_transition_probs(s16, toks)),
+                                  np.asarray(_transition_probs(s4, toks)))
+    np.testing.assert_allclose(np.asarray(_transition_probs(s1, toks, 0)),
+                               np.asarray(_transition_probs(s16, toks)),
                                rtol=1e-5)
     t = s4.sample_all_shards(jax.random.PRNGKey(0), 2, 16)
     assert t.shape == (4, 2, 16)
@@ -275,7 +285,7 @@ def test_markov_published_vocab_has_no_square_array():
     for fn in (lambda kk: s.sample_all_shards(kk, 2, 8),
                lambda kk: s.sample_validation(kk, 2, 8),
                lambda kk: s.regroup(2).sample_shard(kk, 1, 2, 8),
-               lambda kk: s.transition_probs(jnp.arange(4)),
+               lambda kk: _transition_probs(s, jnp.arange(4)),
                lambda kk: _entropy_floor_graph(s)):
         jaxpr = jax.make_jaxpr(fn)(key).jaxpr
         biggest = max(int(np.prod(a.shape)) for a in _avals(jaxpr)
