@@ -68,26 +68,6 @@ def train_argv(rehearse: bool, batch: int, *extra) -> list:
             *[str(a) for a in extra]]
 
 
-class CompileClock:
-    """Sums JAX's backend-compile durations (persistent-cache fetches
-    included) and counts persistent-cache hits, via jax.monitoring."""
-
-    def __init__(self):
-        from jax import monitoring
-        self.seconds = 0.0
-        self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._duration)
-        monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 def timed_recorder(transport: str):
     """A RunRecorder that stamps the host clock each time a scanned
     chunk's metrics reach the host (one chunk = one round here)."""
@@ -115,18 +95,21 @@ def peak_bytes(jax) -> list:
     return out
 
 
-def train_once(train, argv, label: str, clock):
-    """One ``train.run`` call; returns (round records, recorder, notes)."""
+def train_once(train, argv, label: str):
+    """One ``train.run`` call; returns (round records, recorder). The
+    compile seconds are the backend compiles (persistent-cache loads
+    included) of the program's compile log during the call."""
+    from repro.obs import profile
     args = train.make_parser().parse_args(argv)
     rec = timed_recorder(args.transport)
     t0 = time.perf_counter()
-    c0 = clock.seconds
     rounds = [r for r in train.run(args, recorder=rec)
               if r["kind"] == "round"]
     steady = [b - a for a, b in zip(rec.chunk_done, rec.chunk_done[1:])]
+    hits = sum(e.get("cache") == "hit" for e in profile.entries())
     print(f"[{label}] wall {time.perf_counter() - t0:.1f}s, compile "
-          f"{clock.seconds - c0:.1f}s (persistent-cache hits so far: "
-          f"{clock.cache_hits}), steady round "
+          f"{profile.backend_seconds(rec.compiles()):.1f}s "
+          f"(persistent-cache hits so far: {hits}), steady round "
           + (f"{sum(steady) / len(steady):.2f}s" if steady else "n/a")
           + " (informational host-clock times)", flush=True)
     return rounds, rec
@@ -155,7 +138,7 @@ def check_losses(rounds, label: str):
     return None
 
 
-def one_chip(jax, train, rehearse: bool, clock) -> int:
+def one_chip(jax, train, rehearse: bool) -> int:
     from repro.models.registry import get_arch, get_smoke_arch
     arch = (get_smoke_arch if rehearse else get_arch)("diloco_150m")
     shapes = jax.eval_shape(lambda k: arch.init(k)[0],
@@ -165,8 +148,7 @@ def one_chip(jax, train, rehearse: bool, clock) -> int:
     if not rehearse and n != PARAMS_150M:
         return fail(f"expected {PARAMS_150M:,} params, got {n:,}")
     rounds, rec = train_once(
-        train, train_argv(rehearse, BATCH_ONE, "--k", "2"), "one-chip",
-        clock)
+        train, train_argv(rehearse, BATCH_ONE, "--k", "2"), "one-chip")
     print(f"[one-chip] peak_bytes_in_use per device: {peak_bytes(jax)}",
           flush=True)
     fault = check_losses(rounds, "one-chip")
@@ -179,7 +161,7 @@ def one_chip(jax, train, rehearse: bool, clock) -> int:
     return 0
 
 
-def four_chip(jax, train, rehearse: bool, clock) -> int:
+def four_chip(jax, train, rehearse: bool) -> int:
     import numpy as np
     if len(jax.devices()) != 4:
         return fail(f"--four-chip needs 4 devices, found "
@@ -195,7 +177,7 @@ def four_chip(jax, train, rehearse: bool, clock) -> int:
                               "--transport", "sharded",
                               "--stream-fragments", "2", "--pods", pods,
                               "--checkpoint", path)
-            rounds, _ = train_once(train, argv, label, clock)
+            rounds, _ = train_once(train, argv, label)
             peaks = peak_bytes(jax)
             print(f"[{label}] peak_bytes_in_use per device (process "
                   f"peak so far): {peaks}", flush=True)
@@ -256,9 +238,8 @@ def main(argv=None) -> int:
     cache = train.use_compile_cache()
     print(f"chip_smoke: {dev.platform} {dev.device_kind} × "
           f"{len(jax.devices())}, compile cache {cache}", flush=True)
-    clock = CompileClock()
     phase = four_chip if opts.four_chip else one_chip
-    rc = phase(jax, train, opts.rehearse, clock)
+    rc = phase(jax, train, opts.rehearse)
     if rc:
         return rc
     if opts.rehearse:

@@ -106,20 +106,24 @@ def init_state(params, dcfg: DiLoCoConfig) -> DiLoCoState:
 def make_inner_step(loss_fn: Callable, tcfg: TrainConfig,
                     total_steps: int | None = None):
     """One AdamW step for ONE replica. loss_fn(params, batch) ->
-    (loss, metrics). Returns step(params, opt_state, batch, step_idx)."""
+    (loss, metrics). Returns step(params, opt_state, batch, step_idx),
+    whose ops carry the ``diloco.inner`` scope (its AdamW update
+    ``diloco.adamw`` inside it)."""
     sched = make_warmup_cosine(tcfg.inner_lr, tcfg.warmup_steps,
                                total_steps or tcfg.total_steps)
     pol = precision.policy_of(tcfg)
 
+    @jax.named_scope("diloco.inner")
     def step(params, opt_state, batch, step_idx):
         (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, batch)
         grads, gnorm = adamw.clip_by_global_norm(grads, tcfg.grad_clip)
         lr = sched(step_idx)
-        params, opt_state = adamw.update(
-            grads, opt_state, params, lr=lr, b1=tcfg.b1, b2=tcfg.b2,
-            eps=tcfg.eps, weight_decay=tcfg.weight_decay,
-            mode=getattr(tcfg, "kernel_mode", "ref"), policy=pol)
+        with jax.named_scope("diloco.adamw"):
+            params, opt_state = adamw.update(
+                grads, opt_state, params, lr=lr, b1=tcfg.b1, b2=tcfg.b2,
+                eps=tcfg.eps, weight_decay=tcfg.weight_decay,
+                mode=getattr(tcfg, "kernel_mode", "ref"), policy=pol)
         # metrics stay f32 whatever the replica dtype (no-op for f32)
         return params, opt_state, {"loss": loss.astype(jnp.float32),
                                    "gnorm": gnorm, "lr": lr}
@@ -127,6 +131,7 @@ def make_inner_step(loss_fn: Callable, tcfg: TrainConfig,
     return step
 
 
+@jax.named_scope("diloco.inner")
 def inner_phase(inner_step, replica_params, inner_state, batches,
                 step0, *, active_mask=None):
     """H inner steps for all k replicas (scan over H, one replica after
@@ -145,7 +150,8 @@ def inner_phase(inner_step, replica_params, inner_state, batches,
     over k would batch the replicas instead, but the layer scan inside
     the model then moves every stacked leaf to a layer-major layout: a
     second copy of all replicas' params and AdamW moments, which at the
-    150M model with k=2 does not fit one 16 GB chip.
+    150M model with k=2 does not fit one 16 GB chip. Its ops (the loop
+    over replicas, masks, write-back) carry the ``diloco.inner`` scope.
     """
     def one_replica(params, opt_state, batches_h, active):
         def body(carry, xs):
@@ -186,6 +192,7 @@ def inner_phase(inner_step, replica_params, inner_state, batches,
 # outer optimization (lines 11-14)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("diloco.outer")
 def outer_step(state: DiLoCoState, dcfg: DiLoCoConfig, *,
                drop_mask=None, active_mask=None, weights=None,
                compute_cosine: bool = False, bomb_mask=None):
@@ -198,7 +205,8 @@ def outer_step(state: DiLoCoState, dcfg: DiLoCoConfig, *,
     bomb_mask (k,) float: fault injection — 1 poisons the replica's
     outer delta to NaN before the reduce (``faults.Scenario.nan_masks``
     rows; a corrupted-gradient stand-in the guard must catch).
-    Returns (new_state, metrics).
+    Returns (new_state, metrics). Its ops carry the ``diloco.outer``
+    scope.
     """
     k = dcfg.k
     ones = jnp.ones((k,), jnp.float32)
@@ -522,6 +530,10 @@ def make_run(loss_fn, sample_fn, dcfg: DiLoCoConfig, tcfg: TrainConfig,
     R = int(rounds_per_call)
     ev_toks = None if eval_tokens is None else jnp.asarray(eval_tokens)
 
+    @jax.named_scope("diloco.eval")
+    def eval_loss(p):
+        return loss_fn(p, {"tokens": ev_toks})[0].astype(jnp.float32)
+
     def run_fn(state: DiLoCoState, key, drop_masks=None,
                active_masks=None, weights=None, round_offset=0):
         ones = jnp.ones((R, dcfg.k), jnp.float32)
@@ -537,9 +549,7 @@ def make_run(loss_fn, sample_fn, dcfg: DiLoCoConfig, tcfg: TrainConfig,
                 g = round_offset + t + 1          # global 1-based round
                 do_eval = (g % eval_every == 0) | (t == R - 1)
                 m["val_loss"] = jax.lax.cond(
-                    do_eval,
-                    lambda p: loss_fn(p, {"tokens": ev_toks})[0]
-                    .astype(jnp.float32),
+                    do_eval, eval_loss,
                     lambda p: jnp.full((), jnp.nan, jnp.float32),
                     st.global_params)
             return st, m
@@ -557,6 +567,7 @@ def make_run(loss_fn, sample_fn, dcfg: DiLoCoConfig, tcfg: TrainConfig,
 
 def make_eval(loss_fn):
     @jax.jit
+    @jax.named_scope("diloco.eval")
     def eval_fn(params, tokens):
         loss, _ = loss_fn(params, {"tokens": tokens})
         return loss

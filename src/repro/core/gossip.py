@@ -186,6 +186,7 @@ def pairing_edges(k: int, t: int, pairing: str,
                          for i in range(k) if int(pm[i]) != i}))
 
 
+@jax.named_scope("diloco.sync")
 def mix_round(est, partner, mask_tree, *, mix: float, ok=None,
               quant_dtype: str = "float32", kernel_mode: str = "ref",
               exchange=None):
@@ -302,6 +303,7 @@ def make_gossip_round_body(loss_fn, sample_fn, dcfg: DiLoCoConfig,
                 example, *part.masks))
         return mask_cache[0]
 
+    @jax.named_scope("diloco.outer")
     def round_body(state: GossipState, key, drop_mask=None,
                    active_mask=None, weights=None):
         del weights

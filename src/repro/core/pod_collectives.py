@@ -98,6 +98,7 @@ def band_slice(x, k_local: int, axis_name: str = POD_AXIS):
         x, local_band(k_local, axis_name), k_local, 0)
 
 
+@jax.named_scope("diloco.sync")
 def fragment_mean(d_local, m_full, m_local, denom, *, dtype: str,
                   axis: str = POD_AXIS):
     """Reduce one fragment leaf's outer gradient across pods.
@@ -116,6 +117,7 @@ def fragment_mean(d_local, m_full, m_local, denom, *, dtype: str,
     return jnp.tensordot(m_full, gathered, axes=(0, 0)) / denom
 
 
+@jax.named_scope("diloco.sync")
 def fragment_gather(d_local, *, dtype: str, axis: str = POD_AXIS):
     """The collective half of the quantized ``fragment_mean``: gather
     one fragment leaf's per-replica payload over the pod axis WITHOUT
@@ -135,6 +137,7 @@ def fragment_gather(d_local, *, dtype: str, axis: str = POD_AXIS):
     return jax.lax.all_gather(d_local, axis, axis=0, tiled=True)
 
 
+@jax.named_scope("diloco.sync")
 def gather_wire(wire_local, *, axis: str = POD_AXIS):
     """THE packed-wire collective: all-gather one fragment's coalesced
     per-replica wire buffers over the pod axis. wire_local:

@@ -287,6 +287,7 @@ def make_stream_round_body(loss_fn, sample_fn, dcfg: DiLoCoConfig,
     B = batch_size or tcfg.batch_size
     S = seq_len or tcfg.seq_len
 
+    @jax.named_scope("diloco.outer")
     def round_core(sstate: StreamState, key, drop_mask,
                    active_mask, weights):
         from repro.kernels import ops as kops
@@ -354,6 +355,7 @@ def make_stream_round_body(loss_fn, sample_fn, dcfg: DiLoCoConfig,
         frag_regions = (fragments.fragment_regions(part, gp)
                         if packed else None)
 
+        @jax.named_scope("diloco.sync")
         def packed_issue(frag, gp_, src_, residual_):
             """Issue one packed-wire fragment collective: per leaf
             region, quantize the local band's delta (+ error-feedback
@@ -401,6 +403,7 @@ def make_stream_round_body(loss_fn, sample_fn, dcfg: DiLoCoConfig,
                        if res_l is not None else None)
             return gathered, new_res
 
+        @jax.named_scope("diloco.sync")
         def packed_reduce(frag, gathered, m_r, denom_r, pending_):
             """Consume one fragment's gathered wire: dequantize each
             region and mask-reduce in the simulated path's op order,
@@ -422,6 +425,22 @@ def make_stream_round_body(loss_fn, sample_fn, dcfg: DiLoCoConfig,
                 pend_l[r.leaf] = fragments.region_put(
                     pend_l[r.leaf], r, a)
             return jax.tree_util.tree_unflatten(treedef, pend_l)
+
+        @jax.named_scope("diloco.sync")
+        def wire_roundtrip(d, res):
+            """The simulated wire of one leaf's stacked deltas, per
+            replica, with error feedback where ``res`` is kept: returns
+            (payload, new residual or None)."""
+            if res is None:
+                return jax.vmap(lambda dd: kops.quant_roundtrip(
+                    dd, qdtype, mode=kernel_mode))(d), None
+            return jax.vmap(lambda dd, rr: quantize_with_feedback(
+                dd, rr, qdtype, mode=kernel_mode))(d, res)
+
+        @jax.named_scope("diloco.sync")
+        def wire_mean(m_r, d, denom_r):
+            """The simulated all-reduce: the masked replica mean."""
+            return jnp.tensordot(m_r, d, axes=(0, 0)) / denom_r
 
         for steps, acts in sched.phases:
             if steps:
@@ -480,11 +499,8 @@ def make_stream_round_body(loss_fn, sample_fn, dcfg: DiLoCoConfig,
                         # quantize per replica (vmap over the k axis):
                         # a real sender's int4 scale blocks never span
                         # two replicas' deltas, so neither do ours
+                        d, nres = wire_roundtrip(d, res)
                         if res is not None:
-                            d, nres = jax.vmap(
-                                lambda dd, rr: quantize_with_feedback(
-                                    dd, rr, qdtype, mode=kernel_mode)
-                            )(d, res)
                             # only replicas whose packet enters the
                             # average consume their residual; dropped /
                             # inactive replicas never sent, so their
@@ -494,9 +510,6 @@ def make_stream_round_body(loss_fn, sample_fn, dcfg: DiLoCoConfig,
                             new_res.append(
                                 jnp.where((q > 0) & comm, nres, res))
                         else:
-                            d = jax.vmap(
-                                lambda dd: kops.quant_roundtrip(
-                                    dd, qdtype, mode=kernel_mode))(d)
                             new_res.append(res)
                         if defer:
                             # issue only: gather the stacked payload
@@ -517,8 +530,7 @@ def make_stream_round_body(loss_fn, sample_fn, dcfg: DiLoCoConfig,
                                     d, m, m_loc, denom, dtype=qdtype,
                                     axis=axis)
                             else:
-                                a = (jnp.tensordot(m, d, axes=(0, 0))
-                                     / denom)
+                                a = wire_mean(m, d, denom)
                             new_pd.append(jnp.where(q > 0, a, pe))
                         if compute_cosine:
                             new_da.append(jnp.where(q > 0, d, da))
@@ -566,9 +578,8 @@ def make_stream_round_body(loss_fn, sample_fn, dcfg: DiLoCoConfig,
                                     zip(act_l, mk_l)):
                                 if not on:
                                     continue
-                                a = jnp.tensordot(
-                                    m_snap, payload[li],
-                                    axes=(0, 0)) / denom_snap
+                                a = wire_mean(m_snap, payload[li],
+                                              denom_snap)
                                 pend_l[li] = jnp.where(q > 0, a,
                                                        pend_l[li])
                             pending = jax.tree_util.tree_unflatten(

@@ -117,8 +117,10 @@ class MarkovMixture:
         members, logw = self._validation()
         return self._sample_chain(key, members[0], logw[0], batch, seq_len)
 
+    @jax.named_scope("diloco.sample")
     def _sample_chain(self, key, members, logw, batch: int, seq_len: int):
-        """One mixture chain: members (g,) shard ids, logw (g,)."""
+        """One mixture chain: members (g,) shard ids, logw (g,). Its ops
+        carry the ``diloco.sample`` scope in the compiled program."""
         u0, w0, um, wm = self._logit_maps(members)
         g = members.shape[0]
         right = jnp.concatenate([w0, self.alpha * wm], axis=1)

@@ -269,6 +269,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         compiler_params=compat.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
     return out[:, :, :Sq]
@@ -328,6 +329,7 @@ def _fwd_lse(q, k, v, *, causal, window, scale, bq, bk, q_offset,
         compiler_params=compat.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
     return o[:, :, :Sq], lse[:, :, :Sq]
@@ -367,6 +369,7 @@ def _bwd(res, do, *, causal, window, scale, bq, bk, q_offset, interpret):
         compiler_params=compat.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, dltp)[:, :, :Sq]
 
@@ -389,6 +392,7 @@ def _bwd(res, do, *, causal, window, scale, bq, bk, q_offset, interpret):
         compiler_params=compat.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, dltp)
     dk = dk_h[:, :, :Sk].reshape(B, G, rep, Sk, d).sum(2).astype(k.dtype)

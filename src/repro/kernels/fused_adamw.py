@@ -53,10 +53,11 @@ def _to_blocks(tensors, block_rows: int):
     return [to2d(x) for x in tensors], rows_p, br, n
 
 
-def _call_blocked(kernel, tensors_2d, rows_p, br, out_dtypes,
+def _call_blocked(kernel, name, tensors_2d, rows_p, br, out_dtypes,
                   scalars, interpret):
-    """Run ``kernel`` over the (rows_p, 128) layout with the shared
-    SMEM-scalars + one-tile-per-operand grid spec."""
+    """Run ``kernel`` (named ``name`` in the compiled program) over the
+    (rows_p, 128) layout with the shared SMEM-scalars +
+    one-tile-per-operand grid spec."""
     tile = pl.BlockSpec((br, 128), lambda i: (i, 0))
     return pl.pallas_call(
         kernel,
@@ -68,6 +69,7 @@ def _call_blocked(kernel, tensors_2d, rows_p, br, out_dtypes,
                         for d in out_dtypes),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name=name,
         interpret=interpret,
     )(scalars, *tensors_2d)
 
@@ -105,8 +107,8 @@ def fused_adamw(p, g, m, v, *, lr, c1, c2, b1=0.9, b2=0.95, eps=1e-8,
     t2d, rows_p, br, n = _to_blocks((p, g, m, v), block_rows)
     kernel = functools.partial(_adamw_kernel, b1=b1, b2=b2, eps=eps,
                                weight_decay=weight_decay)
-    outs = _call_blocked(kernel, t2d, rows_p, br, out_dtypes,
-                         _scalars(lr, c1, c2), interpret)
+    outs = _call_blocked(kernel, "fused_adamw", t2d, rows_p, br,
+                         out_dtypes, _scalars(lr, c1, c2), interpret)
     return tuple(o.reshape(-1)[:n].reshape(shape).astype(d)
                  for o, d in zip(outs, out_dtypes))
 
@@ -146,7 +148,7 @@ def fused_adamw_mixed(g, m, v, master, *, lr, c1, c2, b1=0.9, b2=0.95,
     t2d, rows_p, br, n = _to_blocks((g, m, v, master), block_rows)
     kernel = functools.partial(_adamw_mixed_kernel, b1=b1, b2=b2,
                                eps=eps, weight_decay=weight_decay)
-    outs = _call_blocked(kernel, t2d, rows_p, br, out_dtypes,
-                         _scalars(lr, c1, c2), interpret)
+    outs = _call_blocked(kernel, "fused_adamw_mixed", t2d, rows_p, br,
+                         out_dtypes, _scalars(lr, c1, c2), interpret)
     return tuple(o.reshape(-1)[:n].reshape(shape).astype(d)
                  for o, d in zip(outs, out_dtypes))

@@ -64,6 +64,7 @@ def outer_nesterov(p, delta, buf, *, lr, momentum=0.9,
                    jax.ShapeDtypeStruct((rows_p, cols), buf.dtype)),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="outer_nesterov",
         interpret=interpret,
     )(scalars, p2, d2, b2)
 

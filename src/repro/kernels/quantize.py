@@ -159,6 +159,7 @@ def quantize_int4(x2d, *, block_rows: int = 256, interpret: bool = False):
                    jax.ShapeDtypeStruct((rows_p, 1), jnp.float32)),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="quantize_int4",
         interpret=interpret,
     )(x2d)
     return codes[:rows], scales[:rows]
@@ -183,6 +184,7 @@ def dequantize_int4(codes, scales, *, block_rows: int = 256,
         out_shape=jax.ShapeDtypeStruct((rows_p, cols), jnp.float32),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="dequantize_int4",
         interpret=interpret,
     )(codes, scales)
     return out[:rows]
@@ -207,6 +209,7 @@ def pack_int4(codes, *, block_rows: int = 256, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((rows_p, cols // 2), jnp.int8),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="pack_int4",
         interpret=interpret,
     )(codes)
     return out[:rows]
@@ -231,6 +234,7 @@ def unpack_int4(packed, *, block_rows: int = 256,
         out_shape=jax.ShapeDtypeStruct((rows_p, cols * 2), jnp.int8),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="unpack_int4",
         interpret=interpret,
     )(packed)
     return out[:rows]
@@ -261,6 +265,7 @@ def quantize_pack_int4(x2d, *, block_rows: int = 256,
                    jax.ShapeDtypeStruct((rows_p, cols), jnp.float32)),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="quantize_pack_int4",
         interpret=interpret,
     )(x2d)
     return packed[:rows], scales[:rows], local[:rows]
@@ -288,6 +293,7 @@ def unpack_dequantize_int4(packed, scales, *, block_rows: int = 256,
         out_shape=jax.ShapeDtypeStruct((rows_p, cols * 2), jnp.float32),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="unpack_dequantize_int4",
         interpret=interpret,
     )(packed, scales)
     return out[:rows]
@@ -319,6 +325,7 @@ def unpack_dequantize_reduce(packed, scales, m, *, block_rows: int = 256,
         out_shape=jax.ShapeDtypeStruct((rows_p, cols * 2), jnp.float32),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="unpack_dequantize_reduce",
         interpret=interpret,
     )(packed, scales, m3)
     return out[:rows]
@@ -341,6 +348,7 @@ def fake_quant(x, dtype: str, *, block_rows: int = 256,
         out_shape=jax.ShapeDtypeStruct((rows_p, 128), jnp.float32),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="fake_quant",
         interpret=interpret,
     )(x2d)
     return out.reshape(-1)[:n].reshape(shape).astype(out_dtype)

@@ -76,6 +76,7 @@ def sign_prune(x, frac: float, *, block_rows: int = 64,
         out_shape=jax.ShapeDtypeStruct((R_p, C_p), x.dtype),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="sign_prune",
         interpret=interpret,
     )(xp)
     return out[:R, :C]

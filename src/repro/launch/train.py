@@ -31,7 +31,7 @@ import numpy as np
 from repro import resilience
 from repro.checkpoint import checkpoint as ckpt
 from repro.configs.base import DiLoCoConfig, TrainConfig
-from repro.core import diloco, faults, schedules
+from repro.core import diloco, faults, pod_collectives, schedules
 from repro.data.sharding import make_regime, shard_weights
 from repro.models.registry import get_arch, get_smoke_arch
 from repro.obs import metrics as obs_metrics
@@ -351,21 +351,93 @@ def _run_async_phase(args, dcfg, tcfg, loss_fn, sampler, params,
     return rec.records
 
 
+def _init_transport_state(args, dcfg, params, rec):
+    """The DiLoCo phase's state for the configured transport, placed
+    where it runs. Returns (state, mesh, plan, frag_wire,
+    round_wire): the pod mesh of the sharded transport (else None),
+    the streaming sync plan, the gossip per-fragment exchange bytes
+    and the classic/streaming bytes per replica and round."""
+    mesh = None
+    frag_wire = None           # gossip: per-fragment exchange bytes
+    round_wire = None          # classic/streaming: bytes/replica/round
+    plan = ()
+    if dcfg.transport == "gossip":
+        from repro.core import gossip
+        state = gossip.init_state(params, dcfg)
+        frag_wire = gossip.frag_bytes(params, dcfg)
+        rec.attach_wire_plan([{"fragment": i, "wire_bytes": float(b),
+                               "wire_dtype": dcfg.outer_grad_dtype}
+                              for i, b in enumerate(frag_wire)])
+        rec.note(f"gossip transport: {dcfg.gossip_pairing} pairing, "
+                 f"mix={dcfg.gossip_mix}, "
+                 f"P={max(1, dcfg.streaming_fragments)} fragment(s), "
+                 f"{max(frag_wire)} B/exchange")
+    elif dcfg.streaming_fragments:
+        from repro.core import streaming
+        plan = streaming.sync_plan(params, dcfg)
+        round_wire = sum(row["wire_bytes"] for row in plan)
+        rec.attach_wire_plan(plan)
+        if dcfg.transport == "sharded":
+            from repro.launch.mesh import make_pod_mesh
+            # default: the largest pod count that bands k evenly AND
+            # tiles the visible devices (min(k, devices) alone crashes
+            # on e.g. k=4 over 6 devices although pods=2 works)
+            n_dev = jax.device_count()
+            pods = args.pods or max(
+                (p for p in range(2, args.k + 1)
+                 if args.k % p == 0 and n_dev % p == 0), default=1)
+            if pods < 2:
+                raise SystemExit(
+                    "--transport sharded needs >= 2 pods, but no pod "
+                    f"count >= 2 divides both k={args.k} and the "
+                    f"{jax.device_count()} visible device(s) — a "
+                    "1-pod mesh would silently run zero real "
+                    "cross-pod collectives. On a CPU host set "
+                    "XLA_FLAGS=--xla_force_host_platform_device_"
+                    "count=N (a multiple of k) before jax starts")
+            mesh = make_pod_mesh(pods)
+            # each pod builds its own band: k stacked replicas would not
+            # fit one device at published widths
+            state = pod_collectives.init_on_mesh(
+                lambda p: streaming.init_state(p, dcfg), params, mesh)
+            rec.note(f"sharded transport: "
+                     f"{pod_collectives.pods_of(mesh)} "
+                     f"pods × {args.k // pod_collectives.pods_of(mesh)} "
+                     "replicas/pod")
+        else:
+            state = streaming.init_state(params, dcfg)
+    else:
+        state = diloco.init_state(params, dcfg)
+        round_wire = diloco.outer_wire_bytes(params, dcfg)
+        rec.attach_wire_plan([{"fragment": 0, "send_step": args.H,
+                               "apply_step": args.H,
+                               "wire_bytes": float(round_wire),
+                               "wire_dtype": dcfg.outer_grad_dtype}])
+    return state, mesh, plan, frag_wire, round_wire
+
+
 def run(args, recorder=None):
     """Drive the configured run end-to-end. ``recorder`` overrides the
     run's ``RunRecorder`` (benchmarks pass a silenced one and inspect
     its counters); by default one is built from ``--log-format``.
-    Returns the unified record history (``recorder.records``)."""
-    arch, cfg, dcfg, tcfg, sampler = build(args)
+    Returns the unified record history (``recorder.records``).
+
+    The run marks its host work with profiler spans (``obs/profile.py``
+    lists them); the recorder keeps the set-up spans and the compile
+    log for ``--out``."""
+    rec = recorder if recorder is not None else obs_metrics.RunRecorder(
+        transport=args.transport, log_format=args.log_format)
+    with rec.setup("build"):
+        arch, cfg, dcfg, tcfg, sampler = build(args)
     loss_fn = lambda p, b: arch.loss(p, b)
     key = jax.random.PRNGKey(args.seed)
     key, init_key = jax.random.split(key)
-    params, _ = arch.init(init_key, cfg)
+    with rec.setup("init"):
+        params, _ = arch.init(init_key, cfg)
     ev = diloco.make_eval(loss_fn)
-    val = sampler.sample_validation(jax.random.PRNGKey(10_000),
-                                    args.eval_batch, args.seq)
-    rec = recorder if recorder is not None else obs_metrics.RunRecorder(
-        transport=args.transport, log_format=args.log_format)
+    with rec.setup("validation"):
+        val = sampler.sample_validation(jax.random.PRNGKey(10_000),
+                                        args.eval_batch, args.seq)
     rec.manifest.setdefault("config", dict(vars(args)))
 
     # ---- resilience: durable snapshots + resume picker ----
@@ -414,63 +486,9 @@ def run(args, recorder=None):
     if dcfg.transport == "async":
         return _run_async_phase(args, dcfg, tcfg, loss_fn, sampler,
                                 params, ev, val, rec)
-    mesh = None
-    frag_wire = None           # gossip: per-fragment exchange bytes
-    round_wire = None          # classic/streaming: bytes/replica/round
-    plan = ()
-    if dcfg.transport == "gossip":
-        from repro.core import gossip
-        state = gossip.init_state(params, dcfg)
-        frag_wire = gossip.frag_bytes(params, dcfg)
-        rec.attach_wire_plan([{"fragment": i, "wire_bytes": float(b),
-                               "wire_dtype": dcfg.outer_grad_dtype}
-                              for i, b in enumerate(frag_wire)])
-        rec.note(f"gossip transport: {dcfg.gossip_pairing} pairing, "
-                 f"mix={dcfg.gossip_mix}, "
-                 f"P={max(1, dcfg.streaming_fragments)} fragment(s), "
-                 f"{max(frag_wire)} B/exchange")
-    elif dcfg.streaming_fragments:
-        from repro.core import streaming
-        plan = streaming.sync_plan(params, dcfg)
-        round_wire = sum(row["wire_bytes"] for row in plan)
-        rec.attach_wire_plan(plan)
-        if dcfg.transport == "sharded":
-            from repro.core import pod_collectives
-            from repro.launch.mesh import make_pod_mesh
-            # default: the largest pod count that bands k evenly AND
-            # tiles the visible devices (min(k, devices) alone crashes
-            # on e.g. k=4 over 6 devices although pods=2 works)
-            n_dev = jax.device_count()
-            pods = args.pods or max(
-                (p for p in range(2, args.k + 1)
-                 if args.k % p == 0 and n_dev % p == 0), default=1)
-            if pods < 2:
-                raise SystemExit(
-                    "--transport sharded needs >= 2 pods, but no pod "
-                    f"count >= 2 divides both k={args.k} and the "
-                    f"{jax.device_count()} visible device(s) — a "
-                    "1-pod mesh would silently run zero real "
-                    "cross-pod collectives. On a CPU host set "
-                    "XLA_FLAGS=--xla_force_host_platform_device_"
-                    "count=N (a multiple of k) before jax starts")
-            mesh = make_pod_mesh(pods)
-            # each pod builds its own band: k stacked replicas would not
-            # fit one device at published widths
-            state = pod_collectives.init_on_mesh(
-                lambda p: streaming.init_state(p, dcfg), params, mesh)
-            rec.note(f"sharded transport: "
-                     f"{pod_collectives.pods_of(mesh)} "
-                     f"pods × {args.k // pod_collectives.pods_of(mesh)} "
-                     "replicas/pod")
-        else:
-            state = streaming.init_state(params, dcfg)
-    else:
-        state = diloco.init_state(params, dcfg)
-        round_wire = diloco.outer_wire_bytes(params, dcfg)
-        rec.attach_wire_plan([{"fragment": 0, "send_step": args.H,
-                               "apply_step": args.H,
-                               "wire_bytes": float(round_wire),
-                               "wire_dtype": dcfg.outer_grad_dtype}])
+    with rec.setup("state"):
+        state, mesh, plan, frag_wire, round_wire = \
+            _init_transport_state(args, dcfg, params, rec)
     del params                # the state holds its own copy
     # ---- resume + (re-)placement ----
     # Snapshots live at HOST placement: the example captured here (only
@@ -645,17 +663,19 @@ def run(args, recorder=None):
             # round_offset keeps the in-graph eval cadence globally
             # aligned across chunk boundaries (traced: chunks of equal
             # size share one compiled function)
-            state, ms = get_run(n)(state, key,
-                                   jnp.asarray(drops[t:t + n]),
-                                   jnp.asarray(acts[t:t + n]), weights,
-                                   round_offset=t)
+            with jax.profiler.TraceAnnotation("diloco.dispatch"):
+                state, ms = get_run(n)(state, key,
+                                       jnp.asarray(drops[t:t + n]),
+                                       jnp.asarray(acts[t:t + n]),
+                                       weights, round_offset=t)
             key = ms.pop("next_key")
             ms = rec.ingest_chunk(ms)
-            for i in range(n):
-                evaled = ((t + i + 1) % args.eval_every == 0
-                          or i == n - 1)
-                emit_round(t + i, ms, i, evaled=evaled,
-                           round_key=None if subs is None else subs[i])
+            with jax.profiler.TraceAnnotation("diloco.emit"):
+                for i in range(n):
+                    evaled = ((t + i + 1) % args.eval_every == 0
+                              or i == n - 1)
+                    emit_round(t + i, ms, i, evaled=evaled,
+                               round_key=None if subs is None else subs[i])
             t += n
             # (1) scripted kill: BEFORE this boundary's snapshot, so
             # the resume has to replay the crashed round from the last
@@ -667,28 +687,30 @@ def run(args, recorder=None):
             # materialized; on anomaly, roll back to the last good
             # snapshot and replay with the in-graph guard armed
             if guard is not None:
-                losses = [float(ms["val_loss"][i])
-                          if ((t - n + i + 1) % args.eval_every == 0
-                              or i == n - 1)
-                          else float(ms["inner_loss"][i])
-                          for i in range(n)]
-                bad = guard.observe_chunk(t - n, losses)
-                if bad and mgr is not None and guard.can_rollback():
-                    back = mgr.latest_good()
-                    if back is not None and back < t:
-                        state, key, t = load_snapshot(back)
-                        guard.rolled_back(to_round=back,
-                                          skip_round=bad[0]["round"])
-                        guarded = True
-                        continue
+                with jax.profiler.TraceAnnotation("diloco.guard"):
+                    losses = [float(ms["val_loss"][i])
+                              if ((t - n + i + 1) % args.eval_every == 0
+                                  or i == n - 1)
+                              else float(ms["inner_loss"][i])
+                              for i in range(n)]
+                    bad = guard.observe_chunk(t - n, losses)
+                    if bad and mgr is not None and guard.can_rollback():
+                        back = mgr.latest_good()
+                        if back is not None and back < t:
+                            state, key, t = load_snapshot(back)
+                            guard.rolled_back(to_round=back,
+                                              skip_round=bad[0]["round"])
+                            guarded = True
+                            continue
             # (3) durable snapshot at the cadence (host placement is
             # restored by the example on load, so a snapshot taken on
             # a pods=p mesh resumes under pods=p')
             if ckpt_every and (t % ckpt_every == 0 or t == args.rounds):
-                mgr.save(t, resilience.wrap(state, key, t),
-                         metadata={"transport": args.transport,
-                                   "k": args.k, "H": args.H,
-                                   "rounds_done": t})
+                with jax.profiler.TraceAnnotation("diloco.snapshot"):
+                    mgr.save(t, resilience.wrap(state, key, t),
+                             metadata={"transport": args.transport,
+                                       "k": args.k, "H": args.H,
+                                       "rounds_done": t})
 
     elapsed = time.time() - t0
     floor = sampler.entropy_floor()
@@ -704,7 +726,6 @@ def run(args, recorder=None):
             # rounds_per_call=1 lowering (no compile — stream_overlap
             # reads the pre-optimization text) keeps the per-round
             # offsets exact regardless of the chunking above.
-            from repro.core import pod_collectives as _pc
             from repro.launch import hlo_analysis as _hlo
             run1 = diloco.make_run(
                 loss_fn, sampler.sample_all_shards, dcfg, tcfg,
@@ -714,7 +735,8 @@ def run(args, recorder=None):
             overlap = _hlo.stream_overlap(
                 run1.lower(state, key).compiler_ir("hlo")
                 .as_hlo_text(),
-                chips_per_pod=jax.device_count() // _pc.pods_of(mesh),
+                chips_per_pod=(jax.device_count()
+                               // pod_collectives.pods_of(mesh)),
                 tau=dcfg.stream_tau)
             rec.note(
                 f"overlap (HLO-measured): {overlap['n_deferred']} "

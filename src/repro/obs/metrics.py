@@ -30,12 +30,21 @@ instead.
 ``to_jsonable`` is the serialization audit: numpy scalars and (numpy
 or jax) arrays in a record must not crash ``json.dump`` — they are
 converted, not trusted to be Python types.
+
+The recorder also keeps what a profile taken later cannot see: the
+run's set-up spans (``setup``) and the compiles since it was made
+(``compiles``, from ``obs/profile.py``'s log). Both go into the
+dumped manifest, never to the console.
 """
 from __future__ import annotations
 
+import contextlib
 import json
+import time
 
 import numpy as np
+
+from repro.obs import profile
 
 SCHEMA_VERSION = 1
 
@@ -117,6 +126,8 @@ class RunRecorder:
         self.records: list = []
         self.wire_bytes_total: float = 0.0
         self.ingest_calls: int = 0
+        self.setup_spans: list = []
+        self._compile_mark = profile.install()
 
     # ---- console plumbing ----
 
@@ -202,10 +213,33 @@ class RunRecorder:
         tree — the recorder's ONLY contact with device values. One call
         per scanned chunk; ``ingest_calls`` counts them, which is how
         ``benchmarks/obs.py`` gates that recording adds no device
-        syncs beyond the chunk boundaries the driver already pays."""
+        syncs beyond the chunk boundaries the scanned loop already pays.
+        Host spans: ``diloco.ingest.wait`` while the device still works
+        on the chunk, ``diloco.ingest.copy`` for the copy to the host."""
         import jax
         self.ingest_calls += 1
-        return jax.tree.map(np.asarray, stacked_metrics)
+        with jax.profiler.TraceAnnotation("diloco.ingest.wait"):
+            jax.block_until_ready(stacked_metrics)
+        with jax.profiler.TraceAnnotation("diloco.ingest.copy"):
+            return jax.tree.map(np.asarray, stacked_metrics)
+
+    # ---- set-up and compiles ----
+
+    @contextlib.contextmanager
+    def setup(self, name: str):
+        """Host span ``diloco.setup.<name>``, also kept in
+        ``setup_spans`` with its ``perf_counter`` start and end."""
+        import jax
+        start = time.perf_counter()
+        with jax.profiler.TraceAnnotation(f"diloco.setup.{name}"):
+            yield
+        self.setup_spans.append({"span": f"diloco.setup.{name}",
+                                 "start": start,
+                                 "end": time.perf_counter()})
+
+    def compiles(self) -> list:
+        """The compile log's entries since this recorder was made."""
+        return profile.entries(self._compile_mark)
 
     # ---- manifest attachments ----
 
@@ -237,8 +271,11 @@ class RunRecorder:
 
     def payload(self, *, args: dict | None = None) -> dict:
         """The serializable run bundle: superset of the legacy
-        ``{"args", "history"}`` shape plus the manifest."""
-        return to_jsonable({"args": args, "manifest": self.manifest,
+        ``{"args", "history"}`` shape plus the manifest (with the
+        set-up spans and the compile log)."""
+        manifest = dict(self.manifest, setup=self.setup_spans,
+                        compiles=self.compiles())
+        return to_jsonable({"args": args, "manifest": manifest,
                             "history": self.records})
 
     def dump(self, path: str, *, args: dict | None = None) -> str:
