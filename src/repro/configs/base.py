@@ -76,7 +76,10 @@ class ModelConfig:
     remat: bool = True
     logit_softcap: float = 0.0
     init_scale: float = 0.02
-    use_pallas: bool = False    # use Pallas kernels (TPU) instead of jnp ref
+    # Pallas kernel backend of the model's self-attention (ref | auto |
+    # pallas | interpret, as DiLoCoConfig.kernel_mode): the trainer
+    # sets it from the job's kernel mode
+    kernel_mode: str = "ref"
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

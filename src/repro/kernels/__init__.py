@@ -8,8 +8,9 @@ ops.py              backend dispatch (kernel on TPU, jnp oracle elsewhere)
 ref.py              pure-jnp oracles for every kernel
 compat.py           Pallas TPU API names across jax releases
 
-The fused optimizer kernels are wired into the training hot path via
-``kernel_mode`` on TrainConfig (inner AdamW) and DiLoCoConfig (outer
-Nesterov, sign pruning): ``ref`` = legacy jnp tree maps, ``auto`` =
-kernels on TPU / oracles elsewhere, ``pallas``/``interpret`` = forced.
+The kernels are wired into the training hot path via ``kernel_mode`` on
+TrainConfig (inner AdamW), DiLoCoConfig (outer Nesterov, sign pruning)
+and ModelConfig (self-attention, set by the trainer): ``ref`` = legacy
+jnp, ``auto`` = kernels on TPU / oracles elsewhere,
+``pallas``/``interpret`` = forced.
 """

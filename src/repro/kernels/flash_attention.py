@@ -8,12 +8,19 @@ max/denominator live in VMEM scratch across the sequential kv grid axis.
 
 Layout: q (B, H, Sq, d); k/v (B, G, Skv, d), GQA via H % G == 0 (the
 kv-head index_map folds h -> h // rep so kv tiles are re-read, not
-replicated, across the query heads of a group).
+replicated, across the query heads of a group). The per-row logsumexp
+and the backward's ``delta`` ride as (B, H, Sq, 1) columns: a
+(1, 1, block_q, 1) block meets the TPU's tiling rule at any head count.
 
 Grid: (B, H, n_qblocks, n_kvblocks) — first three parallel, the kv axis
 "arbitrary" (sequential) so scratch accumulators carry across it.
-Causal/sliding-window masking is applied per-tile from absolute
-positions; fully-masked tiles short-circuit via ``pl.when``.
+Causal/sliding-window masks are built only on the tiles that cut the
+diagonal or the window's edge; tiles no query sees are skipped
+(``pl.when``).
+
+Arithmetic: the matmuls take their operands in ``mxu_dtype`` and
+accumulate in f32; the scale, the running max, the denominator and the
+logsumexp stay f32.
 
 Supports self-attention (Sq == Skv, causal, optional window) — the
 training/prefill hot path. Decode (Sq == 1) uses the jnp ref (a matvec —
@@ -31,13 +38,98 @@ from jax.experimental.pallas import tpu as pltpu
 from . import compat
 
 NEG_INF = -1e30
+# q/kv block sizes the kernel picks from, largest first (multiples of
+# the TPU's 128-lane tile): on a v5e one masked (1024, 1024) tile beat
+# (512, 512) tiles with the causal skip, and walking the masked tile in
+# 128-512 pieces, at (8, 16, 1024, 64) and (4, 12, 1024, 128), forward
+# and backward
+BLOCKS = (1024, 512, 256, 128)
+
+NT = ((1,), (1,))          # a · bᵀ
+NN = ((1,), (0,))          # a · b
+TN = ((0,), (0,))          # aᵀ · b
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                 scale: float, causal: bool, window: int, block_q: int,
-                 block_k: int, n_kv: int, kv_len: int, q_offset: int):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+def block_size(seq_len: int) -> int | None:
+    """The block the kernel tiles a sequence of ``seq_len`` with: the
+    largest of ``BLOCKS`` that divides it, the whole sequence where it
+    is at most one lane tile, else None (the kernel would pad)."""
+    for b in BLOCKS:
+        if seq_len % b == 0:
+            return b
+    return seq_len if seq_len <= BLOCKS[-1] else None
+
+
+def mxu_dtype(dtype, interpret: bool):
+    """Operand dtype of the kernel's matmuls. On the chip, what XLA's
+    default precision makes of an f32 operand there: one bf16 MXU pass
+    with f32 accumulation, unless the program raised
+    ``jax_default_matmul_precision`` (then f32 operands). In interpret
+    mode the operands stay as given, and the backend's own dot treats
+    them at its default precision."""
+    dtype = jnp.dtype(dtype)
+    if interpret or dtype != jnp.float32:
+        return dtype
+    if jax.config.jax_default_matmul_precision in (
+            None, "default", "bfloat16", "fastest"):
+        return jnp.dtype(jnp.bfloat16)
+    return dtype
+
+
+def _dot(a, b, dims, mxu):
+    return jax.lax.dot_general(a.astype(mxu), b.astype(mxu), (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _visit(body, iq, ik, *, block_q, block_k, causal, window, kv_len,
+           kv_pad, q_offset):
+    """Run ``body(ok)`` on tile (iq, ik) where some query sees some key
+    of it: ``ok`` is the (block_q, block_k) visibility mask on tiles that
+    cut the causal diagonal, the window's edge or the padded keys, and
+    None on tiles every query sees whole."""
+    q_first = q_offset + iq * block_q
+    q_last = q_first + block_q - 1
+    k_first = ik * block_k
+    k_last = k_first + block_k - 1
+    live = True
+    edges = [True] if kv_pad else []
+    if causal:
+        live = k_first <= q_last
+        edges.append(k_last > q_first)
+    if window and window > 0:
+        live = jnp.logical_and(live, k_last > q_first - window)
+        edges.append(k_first <= q_last - window)
+
+    def masked():
+        shape = (block_q, block_k)
+        q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        ok = k_pos < kv_len
+        if causal:
+            ok = jnp.logical_and(ok, k_pos <= q_pos)
+        if window and window > 0:
+            ok = jnp.logical_and(ok, k_pos > q_pos - window)
+        body(ok)
+
+    if not edges:
+        pl.when(live)(lambda: body(None))
+    elif kv_pad:
+        pl.when(live)(masked)
+    else:
+        edge = functools.reduce(jnp.logical_or, edges)
+        pl.when(jnp.logical_and(live, edge))(masked)
+        pl.when(jnp.logical_and(live, jnp.logical_not(edge)))(
+            lambda: body(None))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, n_kv, mxu, tile):
+    """o (and, where an lse output is given, the per-row logsumexp
+    L = m + log(l), the one residual the backward kernels need)."""
+    if len(refs) == 5:
+        o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        (o_ref, acc_ref, m_ref, l_ref), lse_ref = refs, None
+    iq, ik = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ik == 0)
     def _init():
@@ -45,76 +137,31 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # absolute positions of this tile's queries and keys
-    q_pos = q_offset + iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-
-    # tile-level skip: causal => skip tiles strictly above the diagonal;
-    # window => skip tiles entirely left of the window
-    q_first = q_offset + iq * block_q
-    q_last = q_first + block_q - 1
-    k_first = ik * block_k
-    k_last = k_first + block_k - 1
-    live = True
-    if causal:
-        live = k_first <= q_last
-    if window and window > 0:
-        live = jnp.logical_and(live, k_last > q_first - window)
-
-    @pl.when(live)
-    def _compute():
+    def body(ok):
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bq, bk)
-        ok = k_pos < kv_len
-        if causal:
-            ok = jnp.logical_and(ok, k_pos <= q_pos)
-        if window and window > 0:
-            ok = jnp.logical_and(ok, k_pos > q_pos - window)
-        s = jnp.where(ok, s, NEG_INF)
-
+        s = _dot(q, k_ref[0, 0], NT, mxu)                    # (bq, bk)
+        if ok is not None:
+            s = jnp.where(ok, s, NEG_INF)
         m_prev = m_ref[:, :1]                                 # (bq, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                                # (bq, bk)
-        corr = jnp.exp(m_prev - m_new)                        # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
         l_ref[:, :1] = l_ref[:, :1] * corr + jnp.sum(p, 1, keepdims=True)
         m_ref[:, :1] = m_new
-        v = v_ref[0, 0].astype(jnp.float32)                   # (bk, d)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + _dot(p, v_ref[0, 0], NN, mxu)
+
+    _visit(body, iq, ik, **tile)
 
     @pl.when(ik == n_kv - 1)
     def _finish():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _attn_kernel_fwd(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
-                     m_ref, l_ref, *, scale, causal, window, block_q,
-                     block_k, n_kv, kv_len, q_offset):
-    """Forward that additionally writes the per-row logsumexp L = m +
-    log(l) — the single residual the backward kernels need to
-    recompute the probabilities on-chip."""
-    _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                 scale=scale, causal=causal, window=window,
-                 block_q=block_q, block_k=block_k, n_kv=n_kv,
-                 kv_len=kv_len, q_offset=q_offset)
-
-    @pl.when(pl.program_id(3) == n_kv - 1)
-    def _store_lse():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        lse_ref[0, 0] = (m_ref[:, :1] + jnp.log(l))[:, 0]
+        if lse_ref is not None:
+            lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, acc_ref, *, scale, causal, window, block_q,
-                   block_k, n_kv, kv_len, q_offset):
+                   dq_ref, acc_ref, *, scale, n_kv, mxu, tile):
     """dq: grid (B, H, n_q, n_kv); kv sequential; p recomputed per tile
     from (q, k, L) — the (Sq, Skv) matrix never exists in HBM."""
     iq, ik = pl.program_id(2), pl.program_id(3)
@@ -123,47 +170,26 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_pos = q_offset + iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    live = True
-    if causal:
-        live = ik * block_k <= q_offset + iq * block_q + block_q - 1
-    if window and window > 0:
-        live = jnp.logical_and(
-            live, ik * block_k + block_k - 1
-            > q_offset + iq * block_q - window)
-
-    @pl.when(live)
-    def _compute():
+    def body(ok):
         q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        ok = k_pos < kv_len
-        if causal:
-            ok = jnp.logical_and(ok, k_pos <= q_pos)
-        if window and window > 0:
-            ok = jnp.logical_and(ok, k_pos > q_pos - window)
-        p = jnp.where(ok, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        k = k_ref[0, 0]
+        p = jnp.exp(_dot(q, k, NT, mxu) - lse_ref[0, 0])
+        if ok is not None:
+            p = jnp.where(ok, p, 0.0)
+        dp = _dot(do_ref[0, 0], v_ref[0, 0], NT, mxu)
+        ds = p * (dp - delta_ref[0, 0])
+        acc_ref[...] += _dot(ds, k, NN, mxu)
+
+    _visit(body, iq, ik, **tile)
 
     @pl.when(ik == n_kv - 1)
     def _finish():
-        dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                    window, block_q, block_k, n_q, kv_len, q_offset):
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, n_q, mxu,
+                    tile):
     """dk/dv: grid (B, H, n_kv, n_q); q sequential; accumulates the
     per-query-head contributions (summed over the GQA group outside)."""
     ik, iq = pl.program_id(2), pl.program_id(3)
@@ -173,41 +199,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_pos = q_offset + iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    live = True
-    if causal:
-        live = ik * block_k <= q_offset + iq * block_q + block_q - 1
-    if window and window > 0:
-        live = jnp.logical_and(
-            live, ik * block_k + block_k - 1
-            > q_offset + iq * block_q - window)
-
-    @pl.when(live)
-    def _compute():
+    def body(ok):
         q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        ok = k_pos < kv_len
-        if causal:
-            ok = jnp.logical_and(ok, k_pos <= q_pos)
-        if window and window > 0:
-            ok = jnp.logical_and(ok, k_pos > q_pos - window)
-        p = jnp.where(ok, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-        dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        do = do_ref[0, 0]
+        p = jnp.exp(_dot(q, k_ref[0, 0], NT, mxu) - lse_ref[0, 0])
+        if ok is not None:
+            p = jnp.where(ok, p, 0.0)
+        dv_acc[...] += _dot(p, do, TN, mxu)
+        dp = _dot(do, v_ref[0, 0], NT, mxu)
+        ds = p * (dp - delta_ref[0, 0])
+        dk_acc[...] += _dot(ds, q, TN, mxu)
+
+    _visit(body, iq, ik, **tile)
 
     @pl.when(iq == n_q - 1)
     def _finish():
@@ -215,64 +218,132 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, q_offset: int = 0,
-                    interpret: bool = False):
-    """q: (B, H, Sq, d); k/v: (B, G, Skv, d). Returns (B, H, Sq, d).
+def _pad_to(x, dim, mult):
+    pad = -x.shape[dim] % mult
+    if pad == 0:
+        return x
+    cfgp = [(0, 0)] * x.ndim
+    cfgp[dim] = (0, pad)
+    return jnp.pad(x, cfgp)
 
-    Sq/Skv are padded to block multiples internally; ``q_offset`` is the
-    absolute position of q[0] (prefill continuation). d should be a
-    multiple of 128 for MXU alignment on real TPUs (not enforced —
-    interpret mode accepts anything).
-    """
-    B, H, Sq, d = q.shape
-    _, G, Sk, _ = k.shape
-    assert H % G == 0, (H, G)
-    rep = H // G
-    scale = d ** -0.5 if scale is None else scale
 
-    bq = min(block_q, max(Sq, 8))
-    bk = min(block_k, max(Sk, 8))
-    Sq_p = -(-Sq // bq) * bq
-    Sk_p = -(-Sk // bk) * bk
-    if Sq_p != Sq:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, Sq_p - Sq), (0, 0)))
-    if Sk_p != Sk:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, Sk_p - Sk), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, Sk_p - Sk), (0, 0)))
-    n_q, n_kv = Sq_p // bq, Sk_p // bk
-
-    kernel = functools.partial(
-        _attn_kernel, scale=scale, causal=causal, window=window,
-        block_q=bq, block_k=bk, n_kv=n_kv, kv_len=Sk,
-        q_offset=q_offset + (Sk - Sq if causal and Sq != Sk else 0))
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, H, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, rep=rep: (b, h // rep, j, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, rep=rep: (b, h // rep, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-        ],
+def _call(kernel, grid, in_specs, out_specs, out_shape, scratch, name,
+          interpret):
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
         compiler_params=compat.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary")),
-        name="flash_attention",
-        interpret=interpret,
-    )(q, k, v)
-    return out[:, :, :Sq]
+        name=name, interpret=interpret)
+
+
+def _plan(q, k, *, causal, window, bq, bk, q_offset):
+    """Block counts and the tile settings ``_visit`` takes."""
+    Sq, Sk = q.shape[2], k.shape[2]
+    n_q, n_kv = -(-Sq // bq), -(-Sk // bk)
+    tile = dict(block_q=bq, block_k=bk, causal=causal, window=window,
+                kv_len=Sk, kv_pad=n_kv * bk != Sk,
+                q_offset=q_offset + (Sk - Sq if causal and Sq != Sk else 0))
+    return n_q, n_kv, tile
+
+
+def _forward(q, k, v, *, causal, window, scale, bq, bk, q_offset, mxu,
+             interpret, lse: bool, name: str):
+    B, H, Sq, d = q.shape
+    rep = H // k.shape[1]
+    n_q, n_kv, tile = _plan(q, k, causal=causal, window=window, bq=bq,
+                            bk=bk, q_offset=q_offset)
+    qspec = pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0))
+    kvspec = pl.BlockSpec((1, 1, bk, d),
+                          lambda b, h, i, j: (b, h // rep, j, 0))
+    out_specs = [qspec]
+    out_shape = [jax.ShapeDtypeStruct((B, H, n_q * bq, d), q.dtype)]
+    if lse:
+        out_specs.append(pl.BlockSpec((1, 1, bq, 1),
+                                      lambda b, h, i, j: (b, h, i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, n_q * bq, 1),
+                                              jnp.float32))
+    outs = _call(
+        functools.partial(_fwd_kernel, scale=scale, n_kv=n_kv, mxu=mxu,
+                          tile=tile),
+        (B, H, n_q, n_kv), [qspec, kvspec, kvspec], out_specs, out_shape,
+        [pltpu.VMEM((bq, d), jnp.float32),
+         pltpu.VMEM((bq, 128), jnp.float32),
+         pltpu.VMEM((bq, 128), jnp.float32)], name, interpret,
+    )(_pad_to(q, 2, bq), _pad_to(k, 2, bk), _pad_to(v, 2, bk))
+    outs = [x[:, :, :Sq] for x in outs]
+    return tuple(outs) if lse else outs[0]
+
+
+def _backward(res, do, *, causal, window, scale, bq, bk, q_offset, mxu,
+              interpret):
+    q, k, v, o, lse = res
+    B, H, Sq, d = q.shape
+    _, G, Sk, _ = k.shape
+    rep = H // G
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)                   # (B,H,Sq,1)
+    n_q, n_kv, tile = _plan(q, k, causal=causal, window=window, bq=bq,
+                            bk=bk, q_offset=q_offset)
+    args = (_pad_to(q, 2, bq), _pad_to(k, 2, bk), _pad_to(v, 2, bk),
+            _pad_to(do, 2, bq), _pad_to(lse, 2, bq), _pad_to(delta, 2, bq))
+    common = dict(scale=scale, mxu=mxu, tile=tile)
+
+    qspec = pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0))
+    kspec = pl.BlockSpec((1, 1, bk, d),
+                         lambda b, h, i, j: (b, h // rep, j, 0))
+    rowspec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0))
+    dq = _call(
+        functools.partial(_bwd_dq_kernel, n_kv=n_kv, **common),
+        (B, H, n_q, n_kv), [qspec, kspec, kspec, qspec, rowspec, rowspec],
+        qspec, jax.ShapeDtypeStruct((B, H, n_q * bq, d), q.dtype),
+        [pltpu.VMEM((bq, d), jnp.float32)], "flash_attention_bwd_dq",
+        interpret)(*args)[:, :, :Sq]
+
+    # dk/dv per QUERY head (grid kv-parallel, q sequential), then summed
+    # over each GQA group's rep query heads (in f32 where rep > 1)
+    qq = pl.BlockSpec((1, 1, bq, d), lambda b, h, j, i: (b, h, i, 0))
+    kq = pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h // rep, j, 0))
+    rq = pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0))
+    okv = pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0))
+    acc_dt = (k.dtype, v.dtype) if rep == 1 else (jnp.float32,) * 2
+    dk, dv = _call(
+        functools.partial(_bwd_dkv_kernel, n_q=n_q, **common),
+        (B, H, n_kv, n_q), [qq, kq, kq, qq, rq, rq], [okv, okv],
+        [jax.ShapeDtypeStruct((B, H, n_kv * bk, d), dt) for dt in acc_dt],
+        [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d), jnp.float32)],
+        "flash_attention_bwd_dkv", interpret)(*args)
+    dk, dv = dk[:, :, :Sk], dv[:, :, :Sk]
+    if rep > 1:
+        dk = dk.reshape(B, G, rep, Sk, d).sum(2)
+        dv = dv.reshape(B, G, rep, Sk, d).sum(2)
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+def _settings(q, k, *, causal, window, scale, block_q, block_k, q_offset,
+              interpret):
+    """Static settings of one call: blocks from the lengths (where the
+    caller names none), the scale, the MXU operand dtype."""
+    assert q.shape[1] % k.shape[1] == 0, (q.shape, k.shape)
+    Sq, Sk = q.shape[2], k.shape[2]
+    bq = block_q or block_size(Sq) or BLOCKS[-1]
+    bk = block_k or block_size(Sk) or BLOCKS[-1]
+    return dict(causal=causal, window=window,
+                scale=q.shape[-1] ** -0.5 if scale is None else scale,
+                bq=min(bq, max(Sq, 8)), bk=min(bk, max(Sk, 8)),
+                q_offset=q_offset, mxu=mxu_dtype(q.dtype, interpret),
+                interpret=interpret)
+
+
+def flash_attention(q, k, v, **kw):
+    """q: (B, H, Sq, d); k/v: (B, G, Skv, d). Returns (B, H, Sq, d).
+
+    The forward of ``make_flash_attention_vjp(**kw)``: Sq/Skv are padded
+    to block multiples internally; blocks default to ``block_size`` of
+    each length.
+    """
+    return make_flash_attention_vjp(**kw)(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -281,161 +352,35 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 # never reaches HBM in either pass)
 # ---------------------------------------------------------------------------
 
-def _pad_to(x, dim, mult):
-    size = x.shape[dim]
-    pad = -size % mult
-    if pad == 0:
-        return x
-    cfgp = [(0, 0)] * x.ndim
-    cfgp[dim] = (0, pad)
-    return jnp.pad(x, cfgp)
-
-
-def _fwd_lse(q, k, v, *, causal, window, scale, bq, bk, q_offset,
-             interpret):
-    B, H, Sq, d = q.shape
-    _, G, Sk, _ = k.shape
-    rep = H // G
-    q = _pad_to(q, 2, bq)
-    k = _pad_to(k, 2, bk)
-    v = _pad_to(v, 2, bk)
-    Sq_p, Sk_p = q.shape[2], k.shape[2]
-    n_q, n_kv = Sq_p // bq, Sk_p // bk
-    kernel = functools.partial(
-        _attn_kernel_fwd, scale=scale, causal=causal, window=window,
-        block_q=bq, block_k=bk, n_kv=n_kv, kv_len=Sk,
-        q_offset=q_offset + (Sk - Sq if causal and Sq != Sk else 0))
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=(B, H, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, rep=rep: (b, h // rep, j, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, rep=rep: (b, h // rep, j, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-        ),
-        out_shape=(jax.ShapeDtypeStruct((B, H, Sq_p, d), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, Sq_p), jnp.float32)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-        ],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary")),
-        name="flash_attention_fwd",
-        interpret=interpret,
-    )(q, k, v)
-    return o[:, :, :Sq], lse[:, :, :Sq]
-
-
-def _bwd(res, do, *, causal, window, scale, bq, bk, q_offset, interpret):
-    q, k, v, o, lse = res
-    B, H, Sq, d = q.shape
-    _, G, Sk, _ = k.shape
-    rep = H // G
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                                  # (B,H,Sq)
-    qp = _pad_to(q, 2, bq)
-    dop = _pad_to(do, 2, bq)
-    lsep = _pad_to(lse, 2, bq)
-    dltp = _pad_to(delta, 2, bq)
-    kp = _pad_to(k, 2, bk)
-    vp = _pad_to(v, 2, bk)
-    Sq_p, Sk_p = qp.shape[2], kp.shape[2]
-    n_q, n_kv = Sq_p // bq, Sk_p // bk
-    off = q_offset + (Sk - Sq if causal and Sq != Sk else 0)
-
-    common = dict(scale=scale, causal=causal, window=window, block_q=bq,
-                  block_k=bk, kv_len=Sk, q_offset=off)
-    qspec = pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0))
-    kspec = pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, rep=rep: (b, h // rep, j, 0))
-    rowspec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, n_kv=n_kv, **common),
-        grid=(B, H, n_q, n_kv),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary")),
-        name="flash_attention_bwd_dq",
-        interpret=interpret,
-    )(qp, kp, vp, dop, lsep, dltp)[:, :, :Sq]
-
-    # dk/dv per QUERY head (grid kv-parallel, q sequential), then summed
-    # over each GQA group's rep query heads
-    kq = pl.BlockSpec((1, 1, bk, d),
-                      lambda b, h, j, i, rep=rep: (b, h // rep, j, 0))
-    qq = pl.BlockSpec((1, 1, bq, d), lambda b, h, j, i: (b, h, i, 0))
-    rq = pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i))
-    okv = pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0))
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, n_q=n_q, **common),
-        grid=(B, H, n_kv, n_q),
-        in_specs=[qq, kq, kq, qq, rq, rq],
-        out_specs=(okv, okv),
-        out_shape=(jax.ShapeDtypeStruct((B, H, Sk_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((B, H, Sk_p, d), v.dtype)),
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary")),
-        name="flash_attention_bwd_dkv",
-        interpret=interpret,
-    )(qp, kp, vp, dop, lsep, dltp)
-    dk = dk_h[:, :, :Sk].reshape(B, G, rep, Sk, d).sum(2).astype(k.dtype)
-    dv = dv_h[:, :, :Sk].reshape(B, G, rep, Sk, d).sum(2).astype(v.dtype)
-    return dq, dk, dv
-
-
 def make_flash_attention_vjp(*, causal: bool = True, window: int = 0,
                              scale: float | None = None,
-                             block_q: int = 128, block_k: int = 128,
+                             block_q: int | None = None,
+                             block_k: int | None = None,
                              q_offset: int = 0,
                              interpret: bool = False):
     """Differentiable flash attention: q (B,H,Sq,d), k/v (B,G,Skv,d).
 
-    Forward saves only (q, k, v, o, logsumexp); both backward kernels
-    recompute probabilities tile-by-tile in VMEM (flash backward)."""
+    ``q_offset`` is the absolute position of q[0] (prefill
+    continuation). Forward saves only (q, k, v, o, logsumexp); both
+    backward kernels recompute probabilities tile-by-tile in VMEM (flash
+    backward)."""
+    settings = functools.partial(
+        _settings, causal=causal, window=window, scale=scale,
+        block_q=block_q, block_k=block_k, q_offset=q_offset,
+        interpret=interpret)
 
     @jax.custom_vjp
     def fa(q, k, v):
-        sc = (q.shape[-1] ** -0.5) if scale is None else scale
-        bq = min(block_q, max(q.shape[2], 8))
-        bk = min(block_k, max(k.shape[2], 8))
-        o, _ = _fwd_lse(q, k, v, causal=causal, window=window, scale=sc,
-                        bq=bq, bk=bk, q_offset=q_offset,
-                        interpret=interpret)
-        return o
+        return _forward(q, k, v, lse=False, name="flash_attention",
+                        **settings(q, k))
 
     def fwd(q, k, v):
-        sc = (q.shape[-1] ** -0.5) if scale is None else scale
-        bq = min(block_q, max(q.shape[2], 8))
-        bk = min(block_k, max(k.shape[2], 8))
-        o, lse = _fwd_lse(q, k, v, causal=causal, window=window,
-                          scale=sc, bq=bq, bk=bk, q_offset=q_offset,
-                          interpret=interpret)
+        o, lse = _forward(q, k, v, lse=True, name="flash_attention_fwd",
+                          **settings(q, k))
         return o, (q, k, v, o, lse)
 
     def bwd(res, do):
-        q = res[0]
-        sc = (q.shape[-1] ** -0.5) if scale is None else scale
-        bq = min(block_q, max(q.shape[2], 8))
-        bk = min(block_k, max(res[1].shape[2], 8))
-        return _bwd(res, do, causal=causal, window=window, scale=sc,
-                    bq=bq, bk=bk, q_offset=q_offset, interpret=interpret)
+        return _backward(res, do, **settings(res[0], res[1]))
 
     fa.defvjp(fwd, bwd)
     return fa

@@ -43,25 +43,34 @@ def _resolve(mode: str):
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _fa_vjp(causal, window, scale, block_q, block_k, interpret):
+def _fa_vjp(causal, window, scale, interpret):
     return _flash.make_flash_attention_vjp(
-        causal=causal, window=window, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret)
+        causal=causal, window=window, scale=scale, interpret=interpret)
+
+
+def flash_attention_engages(mode: str, q) -> bool:
+    """Whether self-attention with queries ``q`` (B, S, H, d) takes the
+    Pallas flash kernel under kernel mode ``mode``: where the mode
+    resolves to the kernels, the kernel tiles S without padding, and
+    ``q`` is one device's array — on no mesh, or inside ``shard_map``
+    with every mesh axis manual. A Pallas call is not partitioned across
+    devices, so a ``q`` on a mesh the compiler partitions (the sharded
+    transport's eval) keeps the XLA path."""
+    if not _resolve(mode)[0] or _flash.block_size(q.shape[1]) is None:
+        return False
+    return all(t == jax.sharding.AxisType.Manual
+               for t in jax.typeof(q).sharding.mesh.axis_types)
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
-                    mode: str = "auto", block_q: int = 128,
-                    block_k: int = 128):
+                    mode: str = "auto"):
     """Differentiable flash attention (custom_vjp with flash backward
-    kernels on the kernel path)."""
+    kernels on the kernel path); blocks follow the lengths."""
     use_kernel, interpret = _resolve(mode)
     if not use_kernel:
-        return ref.flash_attention(q.transpose(0, 2, 1, 3),
-                                   k.transpose(0, 2, 1, 3),
-                                   v.transpose(0, 2, 1, 3),
-                                   causal=causal, window=window,
-                                   scale=scale).transpose(0, 2, 1, 3)
-    fa = _fa_vjp(causal, window, scale, block_q, block_k, interpret)
+        return ref.flash_attention(q, k, v, causal=causal, window=window,
+                                   scale=scale)
+    fa = _fa_vjp(causal, window, scale, interpret)
     out = fa(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
              v.transpose(0, 2, 1, 3))
     return out.transpose(0, 2, 1, 3)

@@ -132,6 +132,9 @@ def scenario_of(args) -> faults.Scenario | None:
 
 def build(args):
     arch = (get_smoke_arch if args.smoke else get_arch)(args.arch)
+    # the model's attention takes the Pallas kernels where the job does
+    arch = dataclasses.replace(
+        arch, cfg=arch.cfg.replace(kernel_mode=args.kernel_mode))
     cfg = arch.cfg
     if not args.stream_fragments and args.transport in ("simulated",
                                                         "sharded"):
@@ -810,8 +813,9 @@ def make_parser():
     ap.add_argument("--cosine-stats", action="store_true")
     ap.add_argument("--kernel-mode", default="ref",
                     choices=["auto", "pallas", "interpret", "ref"],
-                    help="fused optimizer kernels: auto=Pallas on TPU, "
-                         "ref=legacy jnp tree maps (bit-identical)")
+                    help="Pallas kernels of attention and the fused "
+                         "optimizers: auto=Pallas on TPU, ref=legacy jnp "
+                         "(bit-identical)")
     ap.add_argument("--rounds-per-call", type=int, default=0,
                     help="rounds scanned inside one jit "
                          "(0 = all rounds in a single call)")

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops as kops
 from repro.sharding.spec import Boxed
 
 # ---------------------------------------------------------------------------
@@ -415,11 +416,11 @@ def apply_attention(p, x, cfg, *, positions, cache=None, cache_pos=None,
                         q_offset=cache_pos, kv_positions=kv_pos1,
                         kv_valid=kv_valid, chunk=cfg.attn_chunk,
                         kv_shard=cfg.decode_kv_shard or None)
-    elif (cfg.use_pallas and kv_x is None and kv_positions is None
-            and cfg.resolved_head_dim % 128 == 0 and q.shape[1] % 128 == 0):
-        # TPU hot path: Pallas flash kernel (see kernels/flash_attention)
-        from repro.kernels import ops as kops
-        out = kops.flash_attention(q, k, v, causal=causal, window=window)
+    elif (kv_x is None and kv_positions is None
+            and kops.flash_attention_engages(cfg.kernel_mode, q)):
+        # Pallas flash kernel: the (B, H, S, S) scores stay in VMEM tiles
+        out = kops.flash_attention(q, k, v, causal=causal, window=window,
+                                   mode=cfg.kernel_mode)
     else:
         q_offset = 0
         out = attention(q, k, v, causal=causal, window=window,

@@ -5,6 +5,8 @@ GQA grouping, causal/window masks and non-block-aligned lengths.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -62,19 +64,145 @@ def test_flash_attention_dtypes(dtype):
                                want.astype(np.float32), rtol=tol, atol=tol)
 
 
-def test_flash_attention_vs_model_attention():
+MODEL_ATTN_CASES = [
+    # B, S, H, G, d, blocks (None: the kernel's own pick, ops' path)
+    (2, 256, 8, 4, 64, None),
+    (1, 1024, 2, 2, 64, None),         # the 150M cell's head layout
+    (1, 1024, 2, 2, 64, 256),          # causal tile skip
+    (1, 1024, 2, 1, 128, None),        # the 400M cell's head width, GQA
+    (1, 1024, 2, 1, 128, 128),
+]
+
+
+@pytest.mark.parametrize("B,S,H,G,d,blocks", MODEL_ATTN_CASES)
+def test_flash_attention_vs_model_attention(B, S, H, G, d, blocks):
     """The kernel agrees with the model's chunked online-softmax
-    (layers.attention) — two independent formulations."""
+    (layers.attention) — two independent formulations — forward and
+    gradient with respect to q, k and v."""
     from repro.models.layers import attention
+    if blocks is None:
+        # the cells' length takes one whole-sequence tile
+        assert FK.block_size(S) == min(S, 1024)
+        fa = lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
+                                                 mode="interpret")
+    else:
+        vjp = FK.make_flash_attention_vjp(block_q=blocks, block_k=blocks,
+                                          interpret=True)
+        t = lambda x: x.transpose(0, 2, 1, 3)
+        fa = lambda q, k, v: t(vjp(t(q), t(k), t(v)))
     key = jax.random.PRNGKey(7)
-    ks = jax.random.split(key, 3)
-    B, S, H, G, d = 2, 256, 8, 4, 64
+    ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (B, S, H, d))
     k = jax.random.normal(ks[1], (B, S, G, d))
     v = jax.random.normal(ks[2], (B, S, G, d))
-    want = attention(q, k, v, causal=True, chunk=64)
-    out = ops.flash_attention(q, k, v, causal=True, mode="interpret")
+    dout = jax.random.normal(ks[3], (B, S, H, d))
+    want, vjp_want = jax.vjp(
+        lambda q, k, v: attention(q, k, v, causal=True, chunk=64), q, k, v)
+    out, vjp_out = jax.vjp(fa, q, k, v)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    for got, ref_g in zip(vjp_out(dout), vjp_want(dout)):
+        np.testing.assert_allclose(got, ref_g, rtol=5e-4, atol=5e-4)
+
+
+def test_mxu_operands_follow_the_default_matmul_precision():
+    f32, bf16 = jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)
+    assert FK.mxu_dtype(f32, interpret=False) == bf16     # one MXU pass
+    assert FK.mxu_dtype(bf16, interpret=False) == bf16
+    assert FK.mxu_dtype(f32, interpret=True) == f32       # backend's own
+    with jax.default_matmul_precision("highest"):
+        assert FK.mxu_dtype(f32, interpret=False) == f32
+
+
+def test_flash_attention_bf16_operands(monkeypatch):
+    """With the chip's bf16 MXU operands (forced here in interpret mode)
+    the kernel stays within bf16 rounding of the f32 oracle, and differs
+    from its f32-operand run: the operands are rounded."""
+    key = jax.random.PRNGKey(3)
+    ks = jax.random.split(key, 4)
+    q, k, v, dout = (jax.random.normal(kk, (1, 2, 256, 64)) for kk in ks)
+
+    def run():
+        fa = FK.make_flash_attention_vjp(interpret=True)
+        o, vjp = jax.vjp(fa, q, k, v)
+        return (o,) + vjp(dout)
+
+    exact = run()
+    monkeypatch.setattr(FK, "mxu_dtype",
+                        lambda dtype, interpret: jnp.dtype(jnp.bfloat16))
+    rounded = run()
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    o_r, vjp_r = jax.vjp(lambda q, k, v: t(ref.flash_attention(
+        t(q), t(k), t(v))), q, k, v)
+    for got, f32_run, want in zip(rounded, exact, (o_r,) + vjp_r(dout)):
+        scale = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(got, want, atol=2e-2 * scale)
+        assert float(jnp.max(jnp.abs(got - f32_run))) > 1e-4 * scale
+
+
+def _kernel_names(fn, *args) -> list:
+    return re.findall(r"name=(flash_attention\w*)",
+                      str(jax.make_jaxpr(fn)(*args)))
+
+
+@pytest.mark.parametrize("mode,want", [
+    ("ref", []), ("auto", []), ("interpret", ["flash_attention"])])
+def test_apply_attention_takes_the_kernel_by_kernel_mode(mode, want):
+    """Self-attention with no cache runs the Pallas kernel where the
+    job's kernel mode resolves to the kernels (auto: not on the CPU)."""
+    from repro.models.layers import apply_attention, init_attention
+    from repro.models.registry import get_smoke_arch
+    from repro.sharding.spec import unbox
+    cfg = get_smoke_arch("diloco_150m").cfg.replace(kernel_mode=mode)
+    p, _ = unbox(init_attention(jax.random.PRNGKey(0), cfg))
+    x = jnp.ones((2, 128, cfg.d_model))
+    fn = lambda p, x: apply_attention(p, x, cfg,
+                                      positions=jnp.arange(128))[0]
+    assert _kernel_names(fn, p, x) == want
+
+
+def test_flash_attention_engages_only_on_one_devices_arrays():
+    """Off a mesh and inside shard_map (manual axes) the kernel takes
+    the call; on a mesh the compiler partitions it does not."""
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Explicit,))
+    q = jnp.ones((2, 128, 4, 32))
+    seen = {}
+
+    def probe(name):
+        def f(x):
+            seen[name] = ops.flash_attention_engages("interpret", x)
+            return x
+        return f
+
+    jax.jit(probe("plain"))(q)
+    on_mesh = jax.device_put(q, NamedSharding(mesh, P()))
+    jax.jit(probe("mesh"))(on_mesh)
+    jax.jit(jax.shard_map(probe("manual"), mesh=mesh, in_specs=P(),
+                          out_specs=P()))(on_mesh)
+    assert seen == {"plain": True, "mesh": False, "manual": True}
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_smoke_model_trains_and_evaluates_through_the_kernel(mode):
+    """The smoke ``diloco_150m``'s eval forward and training step: every
+    layer's attention runs the kernel under ``interpret`` (the forward,
+    its remat recompute and both backward kernels), none under ``ref``."""
+    from repro.models.registry import get_smoke_arch
+    arch = get_smoke_arch("diloco_150m")
+    cfg = arch.cfg.replace(kernel_mode=mode)
+    params, _ = arch.init(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jnp.zeros((2, 128), jnp.int32)}
+    loss = lambda p: arch.loss(p, batch, cfg=cfg)[0]
+    evals, trains = _kernel_names(loss, params), \
+        _kernel_names(jax.grad(loss), params)
+    if mode == "ref":
+        assert evals == trains == []
+    else:
+        assert evals == ["flash_attention"]
+        assert sorted(trains) == ["flash_attention_bwd_dkv",
+                                  "flash_attention_bwd_dq",
+                                  "flash_attention_fwd",
+                                  "flash_attention_fwd"]
 
 
 # ---------------------------------------------------------------------------

@@ -82,9 +82,7 @@ def _kernel_cases():
     sd = jax.ShapeDtypeStruct
     x2d, codes = sd((256, 128), f32), sd((256, 128), i8)
     packed, scales = sd((256, 64), i8), sd((256, 1), f32)
-    # one head: the log-sum-exp output's (1, 1, 128) block lowers for
-    # a TPU only where it spans the head axis
-    q = sd((1, 1, 128, 128), f32)
+    q = sd((1, 2, 128, 128), f32)
     sc = dict(lr=1e-3, c1=0.1, c2=0.05)
     fa_vjp = flash_attention.make_flash_attention_vjp(
         causal=True, block_q=128, block_k=128)
@@ -109,7 +107,10 @@ def _kernel_cases():
         "fake_quant": (lambda x: quantize.fake_quant(x, "int4"), [x2d]),
         "sign_prune": (lambda x: sign_prune.sign_prune(x, 0.5), [x2d]),
         "flash_attention": (flash_attention.flash_attention, [q] * 3),
-        "flash_attention_fwd": (fa_vjp, [q] * 3),
+        # the differentiated forward (the plain call runs the kernel
+        # without the log-sum-exp, named flash_attention)
+        "flash_attention_fwd": (lambda a, b, c: jax.vjp(
+            fa_vjp, a, b, c)[0], [q] * 3),
         "flash_attention_bwd_dq": (lambda a, b, c: jax.grad(
             lambda a: fa_vjp(a, b, c).sum())(a), [q] * 3),
         "flash_attention_bwd_dkv": (lambda a, b, c: jax.grad(

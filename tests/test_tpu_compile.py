@@ -1,19 +1,21 @@
 """The main path's Pallas kernels compile for a TPU v5e at full width.
 
 Each test compiles one kernel for a described (not attached) ``v5e:2x2``
-chip at a leaf shape of the 150M model (``configs/diloco_150m.py``) and
-checks that the compiled program holds the Mosaic kernel. Nothing runs:
-this guards the TPU compiler's verdict (tiling, VMEM limits) on CPU.
+chip at a leaf shape of the 150M model (``configs/diloco_150m.py``), or
+the attention of the 150M and 400M models, and checks that the compiled
+program holds the Mosaic kernel. Nothing runs: this guards the TPU
+compiler's verdict (tiling, VMEM limits) on CPU.
 """
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import fused_adamw, outer_nesterov
+from repro.kernels import fused_adamw, ops, outer_nesterov
 
 # the 150M model's stacked MLP leaf, a norm vector, the embedding table
 STACKED, VECTOR, EMBED = (12, 896, 3584), (896,), (32_000, 896)
@@ -77,3 +79,35 @@ def test_kernel_compiles_for_v5e(kernel, shape, one_chip,
             for dt in dtypes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the attention of the 150M and 400M models at the cells' batch and
+# length, in the model's (B, S, H, head_dim) layout
+ATTENTION = {"150m": (8, 1024, 16, 64), "400m": (4, 1024, 12, 128)}
+
+
+def _attention(q, k, v):
+    return ops.flash_attention(q, k, v, causal=True, mode="pallas")
+
+
+def _attention_grad(q, k, v):
+    return jax.grad(lambda q, k, v: _attention(q, k, v).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("model", sorted(ATTENTION))
+@pytest.mark.parametrize("fn,kernels", [
+    (_attention, ("flash_attention",)),
+    (_attention_grad, ("flash_attention_fwd", "flash_attention_bwd_dq",
+                       "flash_attention_bwd_dkv"))], ids=["fwd", "grad"])
+def test_flash_attention_compiles_for_v5e(model, fn, kernels, one_chip,
+                                          no_persistent_cache):
+    arg = jax.ShapeDtypeStruct(ATTENTION[model], jnp.float32,
+                               sharding=one_chip)
+    text = jax.jit(fn).lower(arg, arg, arg).compile().as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # a transformation may prefix the instruction's name (jvp_, transpose_)
+    assert sorted(re.sub(r"^.*?(flash_attention\w*?)_*\.\d+$", r"\1",
+                         c.strip().lstrip("%")) for c in calls) \
+        == sorted(kernels), calls
