@@ -2,8 +2,7 @@
 tokens over the device self time of the inner steps alone (scope
 ``diloco.inner``; the AdamW update, ``diloco.adamw``, is left out), the
 cell's chips and their bf16 peak, in percent."""
-from bench import scopes
-from bench.work.model_step import flops_per_token
+from bench import blocks, scopes
 
 
 def read(run):
@@ -11,6 +10,7 @@ def read(run):
         lambda p: scopes.phase(p) == "diloco.inner")
     if not ns:
         return None
-    flops = flops_per_token(run.cfg, run.job["seq"]) * run.tokens
+    flops = (blocks.load(run.cfg).flops_per_token(run.cfg, run.job["seq"])
+             * run.tokens)
     return 100.0 * flops / (ns * 1e-9 * len(run.chips)
                             * run.peaks["bf16_flops_per_s"])

@@ -4,8 +4,8 @@ traced window over the summed device time of the kernel's calls and the
 chip's HBM bandwidth. The kernel is found by its calling convention: a
 ``tpu_custom_call`` with the f32[1] SMEM learning rate first and two
 results (theta, momentum)."""
+from bench import blocks
 from bench import trace as tr
-from bench.work.model_step import n_params
 from bench.work.outer_nesterov import bytes_per_round
 
 
@@ -18,5 +18,6 @@ def read(run):
              if run.lo <= o.start < run.hi and is_nesterov(o.name))
     if ns == 0:
         return None
-    moved = run.rounds * bytes_per_round(n_params(run.cfg))
+    moved = run.rounds * bytes_per_round(
+        blocks.load(run.cfg).n_params(run.cfg))
     return 100.0 * moved / (ns * 1e-9) / run.peaks["hbm_bytes_per_s"]

@@ -1,7 +1,9 @@
 """Plain reference of the first rounds of a classic DiLoCo job on the
-simulated transport, in float32 with matmuls at ``Precision.HIGH``
-(three bfloat16 passes, about 2**-16 relative, against the program's one
-pass), written from the job's description alone.
+simulated transport, in float32, written from the job's description
+alone. The model is the one the configuration's block file gives
+(``bench/blocks/<block>.py``: its weights, its loss and the rows of a
+gradient block), with matmuls at ``Precision.HIGH`` (three bfloat16
+passes, about 2**-16 relative, against the program's one pass).
 
 It regenerates from the seed what the trainer generates (the synthetic
 token streams and the initial weights), with the same random draws, and
@@ -26,9 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import blocks
 from bench.check import leaf_norms
 
-HI = jax.lax.Precision.HIGH        # the model's matmuls
 SAMPLE = jax.lax.Precision.HIGHEST  # the chains' logits: tokens match bit for bit
 RANK = 32               # rank of the synthetic chains' transition logits
 MIN_LR_RATIO = 0.1      # cosine decay floor of the inner learning rate
@@ -88,145 +90,27 @@ def round_tokens(seed, k, alpha, V, key, H, B, S):
 
 
 # ---------------------------------------------------------------------------
-# model: pre-norm decoder, RMSNorm, rotary positions, an MLP (gated or
-# plain, by ``mlp_gated``) and an output head (tied to the embedding, by
-# ``tie_embeddings``)
+# gradients
 # ---------------------------------------------------------------------------
-
-def _normal(key, shape, std):
-    return jax.random.normal(key, shape, jnp.float32) * std
-
-
-def _std(scale, fan_in):
-    return min(scale, (1.0 / max(fan_in, 1)) ** 0.5)
-
-
-def init_params(key, cfg: dict):
-    """Initial weights drawn from ``key`` as the trainer draws them:
-    N(0, min(scale, fan_in**-0.5)) matrices, unit norm scales; no gate
-    in a plain MLP, no head matrix where the head is tied."""
-    D, V, F, L = (cfg["d_model"], cfg["vocab_size"], cfg["d_ff"],
-                  cfg["n_layers"])
-    H, G, hd = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-    s = cfg["init_scale"]
-    ks = jax.random.split(key, 9)
-    ones = lambda: jnp.ones((D,), jnp.float32)
-
-    def layer(lk):
-        bk = jax.random.split(lk, 8)
-        ak = jax.random.split(bk[0], 6)
-        mk = jax.random.split(bk[1], 3)
-        mlp = {"w_up": _normal(mk[0], (D, F), _std(s, D)),
-               "w_down": _normal(mk[1], (F, D), _std(s, F))}
-        if cfg["mlp_gated"]:
-            mlp["w_gate"] = _normal(mk[2], (D, F), _std(s, D))
-        return {
-            "ln1": {"scale": ones()},
-            "attn": {"wq": _normal(ak[0], (D, H, hd), _std(s, D)),
-                     "wk": _normal(ak[1], (D, G, hd), _std(s, D)),
-                     "wv": _normal(ak[2], (D, G, hd), _std(s, D)),
-                     "wo": _normal(ak[3], (H, hd, D), _std(s, H))},
-            "ln2": {"scale": ones()},
-            "mlp": mlp}
-
-    layers = [layer(lk) for lk in jax.random.split(ks[3], L)]
-    params = {"embed": {"table": _normal(ks[0], (V, D), _std(1.0, V))},
-              "ln_f": {"scale": ones()},
-              "stack0": jax.tree.map(lambda *ls: jnp.stack(ls), *layers)}
-    if not cfg["tie_embeddings"]:
-        params["head"] = {"w": _normal(ks[1], (D, V), _std(s, D))}
-    return params
-
-
-def _rmsnorm(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
-                             + eps) * scale
-
-
-def _rope(x, theta):
-    """Rotate interleaved pairs (x[2i], x[2i+1]) of each head by
-    position * theta**(-2i/hd)."""
-    S, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd))
-    ang = (jnp.arange(S, dtype=jnp.float32)[:, None] * inv)[None, :, None]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                     -1).reshape(x.shape)
-
-
-def _layer(cfg, x, lp):
-    eps, theta = cfg["norm_eps"], cfg["rope_theta"]
-    H, G, hd = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-    S = x.shape[1]
-    a = lp["attn"]
-    h = _rmsnorm(x, lp["ln1"]["scale"], eps)
-    q = _rope(jnp.einsum("bsd,dhk->bshk", h, a["wq"], precision=HI), theta)
-    k = _rope(jnp.einsum("bsd,dgk->bsgk", h, a["wk"], precision=HI), theta)
-    v = jnp.einsum("bsd,dgk->bsgk", h, a["wv"], precision=HI)
-    k, v = jnp.repeat(k, H // G, axis=2), jnp.repeat(v, H // G, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q * hd ** -0.5, k, precision=HI)
-    causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=HI)
-    x = x + jnp.einsum("bshk,hkd->bsd", o, a["wo"], precision=HI)
-    m = lp["mlp"]
-    act = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[cfg["act"]]
-    h = _rmsnorm(x, lp["ln2"]["scale"], eps)
-    up = jnp.einsum("bsd,df->bsf", h, m["w_up"], precision=HI)
-    if cfg["mlp_gated"]:
-        up = act(jnp.einsum("bsd,df->bsf", h, m["w_gate"], precision=HI)) * up
-    else:
-        up = act(up)
-    return x + jnp.einsum("bsf,fd->bsd", up, m["w_down"], precision=HI)
-
-
-def loss(cfg, params, tokens):
-    """Mean next-token cross-entropy of ``tokens`` (B, S)."""
-    x = params["embed"]["table"][tokens]
-    x, _ = jax.lax.scan(lambda x, lp: (_layer(cfg, x, lp), None), x,
-                        params["stack0"])
-    x = _rmsnorm(x, params["ln_f"]["scale"], cfg["norm_eps"])
-    head = (params["embed"]["table"].T if cfg["tie_embeddings"]
-            else params["head"]["w"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head, precision=HI)[:, :-1]
-    nll = (jax.nn.logsumexp(logits, -1)
-           - jnp.take_along_axis(logits, tokens[:, 1:, None], -1)[..., 0])
-    return jnp.mean(nll)
-
-
-ROW_BUDGET = 4 << 30    # bytes of activations one block of rows may hold
-
-
-def row_block(cfg: dict, batch: int, seq: int) -> int:
-    """Rows per block of a batch's gradient: the largest divisor of
-    ``batch`` whose f32 activations, kept for the backward pass with no
-    recompute (each layer's scores and probabilities, a dozen tensors of
-    the width and three of the MLP's, and the logits), fit
-    ``ROW_BUDGET``."""
-    per_layer = (2 * cfg["n_heads"] * seq * seq
-                 + seq * (12 * cfg["d_model"] + 3 * cfg["d_ff"]))
-    per_row = 4 * (cfg["n_layers"] * per_layer + 3 * seq * cfg["vocab_size"])
-    return max([r for r in range(1, batch + 1)
-                if batch % r == 0 and r * per_row <= ROW_BUDGET], default=1)
-
 
 def _blocks(tokens, rows):
     B, S = tokens.shape
     return tokens.reshape(B // rows, rows, S)
 
 
-def batch_grad(cfg, params, tokens, rows):
-    """(loss, grads) of the batch mean, accumulated over row blocks."""
-    blocks = _blocks(tokens, rows)
+def batch_grad(block, cfg, params, tokens, rows):
+    """(loss, grads) of the batch mean of ``block``'s loss, accumulated
+    over row blocks."""
+    parts = _blocks(tokens, rows)
 
     def add(carry, t):
-        l, g = jax.value_and_grad(functools.partial(loss, cfg))(params, t)
+        l, g = jax.value_and_grad(functools.partial(block.loss, cfg))(
+            params, t)
         return jax.tree.map(jnp.add, carry, (l, g)), None
 
     zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, params))
-    (l, g), _ = jax.lax.scan(add, zero, blocks)
-    n = blocks.shape[0]
+    (l, g), _ = jax.lax.scan(add, zero, parts)
+    n = parts.shape[0]
     return l / n, jax.tree.map(lambda x: x / n, g)
 
 
@@ -245,11 +129,11 @@ def inner_lr(step, job: dict):
     return jnp.where(step < warm, peak * step / max(warm, 1), peak * cos)
 
 
-def adamw_step(cfg, job, rows, p, m, v, tokens, step):
+def adamw_step(block, cfg, job, rows, p, m, v, tokens, step):
     """One inner step: gradient clipped to a global norm, then AdamW
     with bias correction and decoupled weight decay. ``step`` counts the
     replica's steps before this one."""
-    l, g = batch_grad(cfg, p, tokens, rows)
+    l, g = batch_grad(block, cfg, p, tokens, rows)
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(g)))
     g = jax.tree.map(lambda x: x * jnp.minimum(
         1.0, job["grad_clip"] / (gnorm + 1e-12)), g)
@@ -281,20 +165,17 @@ def run(cfg: dict, job: dict, seed: int, rounds: int, log=lambda msg: None):
         raise ValueError("this reference runs classic DiLoCo on the "
                          "simulated transport; a streaming or sharded job "
                          "names a reference of its own")
-    block = {k: cfg[k] for k in ("family", "pos_emb", "norm", "compute_dtype")}
-    if block != {"family": "dense", "pos_emb": "rope", "norm": "rmsnorm",
-                 "compute_dtype": "float32"}:
-        raise ValueError(f"this reference has no such block: {block}")
+    block = blocks.load(cfg)
     B, S = job["batch"], job["seq"]
-    rows = row_block(cfg, B, S)
+    rows = block.row_block(cfg, B, S)
     V = cfg["vocab_size"]
     alpha = job["data_alpha"]
     key, init_key = jax.random.split(jax.random.PRNGKey(seed))
     theta = jax.device_get(jax.jit(
-        functools.partial(init_params, cfg=cfg))(init_key))
+        functools.partial(block.init_params, cfg=cfg))(init_key))
     theta0 = theta
     buf = jax.tree.map(np.zeros_like, theta)
-    step_fn = jax.jit(functools.partial(adamw_step, cfg, job, rows),
+    step_fn = jax.jit(functools.partial(adamw_step, block, cfg, job, rows),
                       donate_argnums=(0, 1, 2))
     toks_fn = jax.jit(functools.partial(round_tokens, seed, k, alpha, V,
                                         H=H, B=B, S=S))

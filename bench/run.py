@@ -6,8 +6,10 @@ A cell of ``BENCHMARK.json`` names a traffic file
 ``bench/traffic/<traffic>.json`` (the job: its replicas, inner steps,
 batch, transport, and the limits of the output check) and a
 configuration file ``bench/configs/<config>.json`` (the model's
-published sizes and dtypes). The run drives the trainer's own
-entry point, ``repro.launch.train.run``, with the cell's flags:
+published sizes and dtypes, and the kind of block, whose counts and
+plain reference model are in ``bench/blocks/<block>.py``). The run
+drives the trainer's own entry point, ``repro.launch.train.run``, with
+the cell's flags and every model key the configuration file states:
 
   set-up   process start, build, compile or cache load, and the first
            ``compare_rounds`` rounds, whose outputs the check compares
@@ -32,6 +34,7 @@ import time
 T0 = time.perf_counter()            # set-up is timed from here
 
 import argparse
+import dataclasses
 import gc
 import glob
 import importlib.util
@@ -40,6 +43,8 @@ import math
 import os
 import shutil
 import sys
+
+from bench import blocks
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -87,6 +92,8 @@ def load_cell(name: str, smoke: bool = False):
     cfg = load_json(ROOT, files[cell["config"]])
     if smoke:
         job = dict(job, **SMOKE)
+        if "n_params" not in cfg["smoke"]:
+            cfg = {k: v for k, v in cfg.items() if k != "n_params"}
         cfg = dict(cfg, **cfg["smoke"])
     return job, cfg
 
@@ -117,19 +124,37 @@ def train_argv(job: dict, cfg: dict, seed: int, smoke: bool) -> list:
     return argv
 
 
-# the model settings a configuration file states, as the registry names them
-MODEL_KEYS = ("family", "n_layers", "d_model", "n_heads", "n_kv_heads",
-              "head_dim", "d_ff", "vocab_size", "pos_emb", "rope_theta",
-              "norm", "act", "mlp_gated", "tie_embeddings", "init_scale",
-              "compute_dtype", "remat")
+# fields of the model config that the trainer's own flags set
+FLAG_KEYS = frozenset(("param_dtype", "kernel_mode"))
+
+
+def model_keys(cfg: dict) -> list:
+    """The keys of configuration ``cfg`` that set the trainer's model:
+    its fields of ``repro.configs.base.ModelConfig``, bar those the
+    trainer's flags set and the bookkeeping keys (``bench.blocks``).
+    Refuses any other key."""
+    from repro.configs.base import ModelConfig
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    unknown = sorted(set(cfg) - (fields - FLAG_KEYS) - blocks.BOOKKEEPING)
+    if unknown:
+        raise SystemExit(f"configuration keys that are neither a model "
+                         f"key nor bookkeeping: {unknown}")
+    return [k for k in cfg if k in fields and k not in blocks.BOOKKEEPING]
+
+
+def model_values(cfg: dict) -> dict:
+    """key -> value of ``model_keys``; a JSON list becomes a tuple, as
+    the model config holds it."""
+    return {k: tuple(cfg[k]) if isinstance(cfg[k], list) else cfg[k]
+            for k in model_keys(cfg)}
 
 
 def use_config(train, cfg: dict):
     """Hand the trainer the configuration file's model: the registry's
-    model config with every key of ``MODEL_KEYS`` set from the file.
+    model config with every key of ``model_keys`` set from the file.
     Returns an undo."""
     from repro.models.registry import Arch
-    model = {k: cfg[k] for k in MODEL_KEYS}
+    model = model_values(cfg)
     orig = train.get_arch, train.get_smoke_arch
     train.get_arch, train.get_smoke_arch = (
         (lambda name, get=get: Arch(cfg=get(name).cfg.replace(**model)))
@@ -141,17 +166,43 @@ def use_config(train, cfg: dict):
     return undo
 
 
-def check_program(train, args, job: dict, cfg: dict):
-    """Refuse to run a program whose model or optimizer settings differ
-    from the files: the reference follows the files."""
+# model keys whose value the model config resolves from others when 0
+RESOLVED = ("head_dim", "v_head_dim")
+
+
+def program_size(arch) -> int:
+    """Parameters in the trainer's tree of ``arch``, from shapes alone."""
+    import jax
+    return sum(math.prod(x.shape)
+               for x in jax.tree.leaves(arch.abstract_params()[0]))
+
+
+def program_gaps(train, args, job: dict, cfg: dict) -> dict:
+    """name -> (program, file) of every setting in which the program
+    built from ``args`` departs from the files: each model key the
+    configuration states (head widths as resolved), the values the
+    configuration's block implements, its parameter count and the file's
+    against the program's tree, the inner optimizer and the data."""
     arch, mcfg, dcfg, tcfg, sampler = train.build(args)
-    want = {k: cfg[k] for k in MODEL_KEYS}
-    have = {k: getattr(mcfg, k) for k in want}
-    have["head_dim"] = mcfg.resolved_head_dim
+    block = blocks.load(cfg)
+    want = dict(model_values(cfg), **block.FIXED)
+    have = {k: getattr(mcfg, f"resolved_{k}" if k in RESOLVED else k)
+            for k in want}
+    size = program_size(arch)
+    want["n_params (block)"], have["n_params (block)"] = (
+        block.n_params(cfg), size)
+    if "n_params" in cfg:
+        want["n_params"], have["n_params"] = cfg["n_params"], size
     for k in ("b1", "b2", "eps", "weight_decay", "grad_clip"):
         want[k], have[k] = job[k], getattr(tcfg, k)
     want["data_alpha"], have["data_alpha"] = job["data_alpha"], sampler.alpha
-    bad = {k: (have[k], want[k]) for k in want if have[k] != want[k]}
+    return {k: (have[k], want[k]) for k in want if have[k] != want[k]}
+
+
+def check_program(train, args, job: dict, cfg: dict):
+    """Refuse to run a program whose model or optimizer settings differ
+    from the files: the reference and the counts follow the files."""
+    bad = program_gaps(train, args, job, cfg)
     if bad:
         raise SystemExit(f"program differs from the benchmark's files "
                          f"(program, file): {bad}")

@@ -90,7 +90,7 @@ def test_small_trace_kernels_found_by_calling_convention(small):
 def test_step_mfu_divides_by_device_busy_time_not_the_window():
     import types
     from bench.metrics import step_mfu
-    from bench.work.model_step import flops_per_token
+    from bench.blocks.dense import flops_per_token
     ops = {0: [tr.Op("a", 0, 4e9), tr.Op("b", 6e9, 8e9)],
            1: [tr.Op("a", 0, 6e9)]}
     cfg = {"n_layers": 2, "d_model": 64, "n_heads": 2, "n_kv_heads": 2,
